@@ -31,6 +31,21 @@ def row(name: str, us: float, derived, target=None, rel_tol: float = 0.15,
 CSV_HEADER = "name,us_per_call,derived,target,ok"
 
 
+def device() -> dict:
+    """The device the timings ran on, as JAX reports it."""
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
+def device_line(dev: dict) -> str:
+    """Comment line that labels a run's timing rows with their device."""
+    return (f"# device: platform={dev['platform']} kind={dev['kind']} "
+            f"count={dev['count']}")
+
+
 def csv_line(r: dict) -> str:
     """One CSV line per row dict (blank target/ok when unset) — the shared
     print format of benchmarks.run and the standalone CLIs."""

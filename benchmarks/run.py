@@ -58,7 +58,11 @@ def main() -> None:
     ]
 
     from benchmarks import common
+    from repro.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
+    dev = common.device()
+    print(common.device_line(dev))
     print(common.CSV_HEADER)
     n_checked = n_ok = 0
     failed = []
@@ -87,7 +91,7 @@ def main() -> None:
         with open(args.json, "w") as f:
             # requested axis; each row carries its *effective* backend
             json.dump({"smoke": args.smoke, "full": args.full,
-                       "backend": args.backend or "jnp",
+                       "backend": args.backend or "jnp", "device": dev,
                        "rows": all_rows}, f, indent=1, default=str,
                       sort_keys=True)
     print(f"\n# paper-validation: {n_ok}/{n_checked} targets matched", flush=True)

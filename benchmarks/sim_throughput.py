@@ -6,9 +6,10 @@ Pre-refactor baseline (per-channel FabricState list, dict-of-arrays flits,
 same host): compile+first-run 5.5 s, steady state ~1400 cycles/s.
 
 The ``--backend`` axis compares the per-cycle router compute backends
-(``jnp`` vmapped reference vs the ``pallas`` (C, R/K)-gridded kernel,
-interpret mode off TPU) on the same workload: cycles/s for both, plus a
-bit-equivalence check on the delivered-beat counters.
+(``jnp`` vmapped reference vs the ``pallas`` (C, R/K)-gridded kernels,
+compiled on a TPU and interpreted elsewhere) on the same workload:
+cycles/s for both, plus a bit-equivalence check on the delivered-beat
+counters. Every run prints the device its timings come from first.
 
 The ``--scaling`` axis grows the mesh (8x4 -> 16x16 -> 32x32, --full adds
 64x64) and reports a routers x cycles/s curve for the naive per-cycle jnp
@@ -47,8 +48,12 @@ SWEEP_SPEEDUP_TARGET = 3.0  # vmapped sweep vs sequential per-config compiles
 # reachable there: past the decision logic (~0.35 ms/cyc) the step is
 # dominated by the 4 full-FIFO-buffer rewrites per cycle (~0.7 ms/cyc of
 # pure memory traffic on 2x 860 KB buffers), i.e. bandwidth-bound; see
-# docs/ARCHITECTURE.md "Scaling methodology".
-SCALING_SPEEDUP_TARGET = 4.0
+# docs/ARCHITECTURE.md "Scaling methodology". The naive datapath's FIFO
+# pop/push became per-slot selects (the form the TPU compiler lowers),
+# which sped naive up by ~20-30% at 32x32 on an 8-core host CPU while the
+# fast path moved ~3%: fast/naive fell from ~5.0x to 3.9-4.3x there, and
+# the floor keeps its ~25% margin below that.
+SCALING_SPEEDUP_TARGET = 3.0
 
 # the --scaling mesh ladder: (nx, ny, timed cycles, fused super-step k).
 # 64x64 (4096 routers) only runs under --full.
@@ -288,6 +293,7 @@ if __name__ == "__main__":
     import json
 
     from benchmarks import common
+    from repro.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--full", action="store_true")
@@ -302,6 +308,9 @@ if __name__ == "__main__":
     ap.add_argument("--json", default=None,
                     help="write rows (and the scaling curve) to this file")
     args = ap.parse_args()
+    enable_compile_cache()
+    dev = common.device()
+    print(common.device_line(dev))
     print(common.CSV_HEADER)
     all_rows, curve, bad = [], [], []
 
@@ -322,7 +331,7 @@ if __name__ == "__main__":
     if args.json:
         with open(args.json, "w") as f:
             json.dump({"smoke": args.smoke, "full": args.full,
-                       "scaling": curve, "rows": all_rows}, f, indent=1,
-                      default=str, sort_keys=True)
+                       "device": dev, "scaling": curve, "rows": all_rows},
+                      f, indent=1, default=str, sort_keys=True)
     if bad:
         raise SystemExit("failed targets: " + ", ".join(bad))

@@ -279,12 +279,13 @@ if __name__ == "__main__":
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="with --dse: write the frontier artifact JSON")
     ap.add_argument("--workers", type=int, default=None,
-                    help="with --dse: process-pool width (default: one "
-                         "per core, capped at the group count)")
+                    help="with --dse: process-pool width on the CPU "
+                         "backend (default: one per core, capped at the "
+                         "group count; always 1 on an accelerator)")
     ap.add_argument("--backend", default="jnp", choices=("jnp", "pallas"),
                     help="router-cycle compute backend (pallas = the "
-                         "(C, R)-gridded kernel, interpret mode off TPU; "
-                         "bit-identical to jnp)")
+                         "(C, R/K)-gridded kernels, compiled on a TPU and "
+                         "interpreted elsewhere; bit-identical to jnp)")
     args = ap.parse_args()
     if args.dse:
         dse_demo(smoke=args.smoke, json_path=args.json, workers=args.workers)

@@ -14,7 +14,6 @@ hybrid training). Everything is pure jnp + lax collectives.
 """
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
 from functools import partial
 from typing import Any
@@ -22,8 +21,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from repro.runtime import axis_size
 
 
 # ----------------------------------------------------------------------
@@ -106,7 +103,7 @@ def dim_ordered_pmean(x, axes: tuple[str, ...]):
     x = dim_ordered_psum(x, axes)
     n = 1
     for a in axes:
-        n *= axis_size(a)
+        n *= jax.lax.axis_size(a)
     return x / n
 
 
@@ -156,9 +153,9 @@ def multi_stream_sync(grads, cfg: SyncConfig, plan: BucketPlan | None = None,
     buckets = to_buckets(grads, plan)
     n_members = 1
     for a in cfg.intra_axes:
-        n_members *= axis_size(a)
+        n_members *= jax.lax.axis_size(a)
     if cfg.pod_axis is not None:
-        n_members *= axis_size(cfg.pod_axis)
+        n_members *= jax.lax.axis_size(cfg.pod_axis)
 
     new_ef = []
     out = []

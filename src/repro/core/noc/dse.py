@@ -6,9 +6,11 @@ spec points by compiled shape (``FabricSpec.group_key`` + the lowered
 workload's static signature), runs each group through the existing
 ``sim.run_sweep``, and shards groups across whatever the host offers —
 round-robin over ``jax.devices()`` (async dispatch overlaps groups when
-there is more than one device) and, with ``workers > 1``, a spawn-based
-process pool (each worker re-runs :func:`run_dse` on its slice of the
-grid). On the 1-core/1-device CPU fallback both collapse to the plain
+there is more than one device) and, on the CPU backend with
+``workers > 1``, a spawn-based process pool (each worker re-runs
+:func:`run_dse` on its slice of the grid; on an accelerator only the
+process holding the devices can use them, so there is no pool). On the
+1-core/1-device CPU fallback both collapse to the plain
 sequential group loop, so results are bit-identical at every width
 (pinned by ``tests/test_noc_spec.py``).
 
@@ -145,11 +147,13 @@ def run_dse(specs, *, n_cycles: int | None = None, workers: int | None = None,
     group runs through one jit-vmapped ``sim.run_sweep`` — per-point
     results are bit-identical to running ``run_sweep`` on each point
     alone. Groups are round-robined over ``jax.devices()`` (async
-    dispatch overlaps them given >1 device); ``workers > 1`` additionally
-    fans groups out over a spawn process pool. ``workers=None`` picks 1
-    process on a 1-core host (the graceful fallback) and never spawns
-    more workers than there are jobs. ``n_cycles=None`` budgets each
-    group from its busiest endpoint (``_wl_cycles_budget``).
+    dispatch overlaps them given >1 device); on the CPU backend
+    ``workers > 1`` additionally fans groups out over a spawn process
+    pool, and ``workers=None`` picks one process per core, never more
+    than there are jobs. On an accelerator this process holds the
+    devices and a child could not reach them, so ``workers=None`` means
+    1 and ``workers > 1`` raises. ``n_cycles=None`` budgets each group
+    from its busiest endpoint (``_wl_cycles_budget``).
     """
     import jax
 
@@ -160,10 +164,16 @@ def run_dse(specs, *, n_cycles: int | None = None, workers: int | None = None,
                 f"DSE point {sp.spec_hash()} has no workload binding; "
                 "set FabricSpec.workload to score it")
     jobs = build_jobs(specs)
+    on_cpu = jax.default_backend() == "cpu"
     if workers is None:
         import os
 
-        workers = max(1, min((os.cpu_count() or 1), len(jobs)))
+        workers = max(1, min((os.cpu_count() or 1), len(jobs))) if on_cpu else 1
+    elif workers > 1 and not on_cpu:
+        raise ValueError(
+            f"workers={workers}: worker processes cannot use the "
+            f"{jax.default_backend()} devices this process holds; use "
+            "workers=1 (groups already round-robin over jax.devices())")
     if workers > 1 and len(jobs) > 1:
         if return_states:
             raise ValueError("return_states requires workers=1")

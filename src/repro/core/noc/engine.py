@@ -14,9 +14,10 @@ arbitration, wormhole-lock updates, FIFO push/pop — lives in
   inlined here as ``_cycle_one``); ``backend="jnp"`` vmaps it over the
   leading channel axis, so the lax.scan step body contains no Python channel
   loop and the traced op count is independent of the channel count.
-* ``noc_router.py`` is a Pallas kernel gridded over (C, R) — one program per
-  (channel, router) — selected with ``backend="pallas"`` (interpret mode off
-  TPU). Both backends run the same decision functions and are bit-identical
+* ``noc_router.py`` holds the Pallas kernels, gridded over (C, R / K) — one
+  program per (channel, K-router block) — selected with
+  ``backend="pallas"``: compiled on a TPU, interpreted elsewhere. Both
+  backends run the same decision functions and are bit-identical
   (tests/test_noc_backend.py).
 
 Flits are a single int32 array with a trailing field axis (see FLIT_FIELDS /
@@ -330,13 +331,13 @@ def fabric_cycle(st: FabricState, tb: FabricTables, ep_ingress_space: jnp.ndarra
     channel this cycle (a refused flit stays in the router's output buffer:
     memory-server-style backpressure into the fabric).
     ``backend`` selects the per-cycle compute path: ``"jnp"`` (vmapped
-    reference) or ``"pallas"`` ((C, R/K)-gridded kernel with
-    ``router_tile`` routers per program; ``interpret=None`` auto-interprets
-    off TPU). ``fused_fifo`` applies each FIFO's pop+push as one fused
-    gather/select on either backend (same live contents; the naive
-    reference path keeps it off). The backends are bit-identical for any
-    fixed ``fused_fifo``. Returns (state', ep_flit [C, E, NF],
-    ep_valid [C, E])."""
+    reference) or ``"pallas"`` ((C, R/K)-gridded kernels with
+    ``router_tile`` routers per program; ``interpret=None`` compiles them
+    on a TPU and interprets them elsewhere). ``fused_fifo`` applies each
+    FIFO's pop+push as one fused select per slot on either backend (same
+    live contents; the naive reference path keeps it off). The backends
+    are bit-identical for any fixed ``fused_fifo``. Returns (state',
+    ep_flit [C, E, NF], ep_valid [C, E])."""
     if backend == "jnp" and not fused_fifo:
         return _cycle_all(st, tb, ep_ingress_space)
     if tb.fork_out is not None:

@@ -61,8 +61,9 @@ class NocParams:
     n_channels: int = 3
 
     # per-cycle router compute backend: "jnp" (vmapped reference) or
-    # "pallas" ((C, ceil(R/K))-gridded kernel, interpreted off TPU).
-    # Bit-identical; see repro.kernels.noc_router and
+    # "pallas" ((C, R/K)-gridded kernels, compiled on a TPU and
+    # interpreted elsewhere; fused_cycles > 1 runs interpreted only and
+    # raises on a TPU). Bit-identical; see repro.kernels.noc_router and
     # tests/test_noc_backend.py.
     backend: str = "jnp"
 
@@ -73,9 +74,10 @@ class NocParams:
     # slots / buffer garbage differ.
     step_impl: str = "fast"
 
-    # Pallas grid tiling: K routers per program (grid (C, ceil(R/K))).
-    # The effective tile is the largest divisor of R <= router_tile, so any
-    # value is valid; 0 means "whole fabric per program" (K = R).
+    # Pallas grid tiling: K routers per program (grid (C, R/K)). The
+    # effective tile is the largest multiple of 8 that divides R and is
+    # <= router_tile (what the TPU accepts as a block's sublane dim), else
+    # the whole fabric, so any value is valid; 0 means K = R.
     router_tile: int = 8
 
     # multi-cycle super-stepping: cycles the fabric advances per fused

@@ -2,45 +2,46 @@
 
 One simulated cycle of the channel-batched fabric is two ``pallas_call``s,
 each with ``grid=(n_channels, n_routers / K)`` — one program per (channel,
-K-router block). ``K`` (``NocParams.router_tile``) amortizes program
-dispatch and maps blocks onto real TPU/GPU lanes instead of 1-router
-programs; the effective tile is the largest divisor of R <= K so no
-padding is ever needed. The two calls per cycle are:
+K-router block) — with the link stage between them in XLA:
 
-1. **arb** — every program runs round-robin output arbitration for its
-   router block from the cycle-start snapshot (its own input heads,
-   occupancy, wormhole locks and routing-table rows) and emits the
-   decisions: pop/grant masks, the chosen flits, updated rr/wormhole
-   state, and whether each input FIFO has space after its pops
-   (``in_space``).
-2. **apply** — every program consumes its own decisions plus the
-   fabric-wide snapshot (all output heads/occupancy and ``in_space``, which
-   is exactly the cross-router information a physical link sees) to resolve
-   link traversals, then applies the FIFO pops/pushes for its block.
+1. **request lookup** (XLA) — ``ref.request_ports`` maps every input head
+   to the output slot it requests (the routing-table gather, plus the VC
+   expansion when ``n_vcs > 1``).
+2. **arb** (kernel) — every program runs round-robin output arbitration
+   for its router block from the cycle-start snapshot (its own requests,
+   heads, occupancy and wormhole locks) and emits the decisions: pop/grant
+   masks, the chosen flits, updated rr/wormhole state, and whether each
+   input FIFO has space after its pops (``in_space``).
+3. **link** (XLA) — ``ref.link_stage`` resolves every link traversal and
+   endpoint delivery from the fabric-wide snapshot plus ``in_space``.
+   Link acceptance depends on the *downstream* router's arbitration pops,
+   so ``in_space`` of every router must be known before any link decision;
+   that arb -> link barrier is the only per-cycle synchronization. The
+   link stage gathers across routers, which Mosaic cannot lower and which
+   would make every program read the whole fabric.
+4. **apply** (kernel) — every program applies the FIFO pops/pushes of its
+   block (``ref.apply_cycle``).
 
-The split is required because link acceptance depends on the *downstream*
-router's arbitration pops: ``in_space`` of every router must be globally
-visible before any link decision. That arb -> link barrier is the *only*
-per-cycle synchronization, which is what makes the multi-cycle fusion
-below legal.
+``K`` (``NocParams.router_tile``) amortizes program dispatch. It is the
+second-to-last axis of the ``[C, R, P]`` counter blocks, which the TPU
+tiles by 8 sublanes, so ``effective_tile`` only picks multiples of 8 that
+divide R, or the whole fabric.
 
-``router_cycles_fused_pallas`` exploits it: one ``pallas_call`` per
-channel block runs N simulated cycles in a ``fori_loop`` whose carry (the
-whole channel's fabric state plus the endpoint egress queues) stays
-resident in kernel memory (VMEM on TPU) instead of round-tripping through
-HBM every ``lax.scan`` step, with ``input_output_aliases`` donating the
-state buffers in place. Endpoint ingress (egress-queue injection) is
-threaded through the loop; deliveries/waiting masks are recorded per cycle
-for the endpoint phases that follow (see ``sim.Sim.step_super``).
+``router_cycles_fused_pallas`` runs N simulated cycles in one kernel per
+channel: a ``fori_loop`` whose carry (the whole channel's fabric state plus
+the endpoint egress queues) stays resident in kernel memory, with
+``input_output_aliases`` donating the state buffers in place. Its body
+(``ref.fused_cycle_body``) gathers across routers inside the kernel, so it
+runs in interpret mode only; on a TPU ``ops.router_cycles_fused`` refuses
+it (see ``FUSED_TPU_REFUSAL``).
 
 All decision math is imported from ``repro.kernels.noc_router.ref`` — the
 functions are rank-generic over the leading router axis, so the Pallas
 programs (R-blocks of K) execute the very same code as the vmapped jnp
 reference (full R), making the backends bit-identical by construction.
-
-On CPU CI this runs with ``interpret=True`` (the grid becomes a scanned
-loop, still jit-able inside ``lax.scan``); on TPU the same kernels compile
-natively. Use ``repro.kernels.noc_router.ops`` for the backend-dispatching
+The per-cycle kernels compile for the TPU (checked against a described
+v5e in ``tests/test_tpu_compile.py``); off the TPU they run in interpret
+mode. Use ``repro.kernels.noc_router.ops`` for the backend-dispatching
 entry points.
 """
 from __future__ import annotations
@@ -54,156 +55,77 @@ from jax.experimental import pallas as pl
 from repro.kernels.noc_router import ref
 from repro.kernels.noc_router.ref import NF, NRED
 
+# what the TPU compiler (Mosaic) refuses in the fused multi-cycle kernel,
+# which therefore runs in interpret mode only; ops.py raises this on a TPU
+FUSED_TPU_REFUSAL = (
+    "the fused multi-cycle Pallas kernel does not compile for TPU: Mosaic "
+    "refuses its per-channel (1, E) blocks (the last two block dims must be "
+    "multiples of (8, 128) or the whole array) and has no lowering for the "
+    "gathers of ref.fused_cycle_body; use fused_cycles=1 or backend='jnp'")
+
 
 def effective_tile(router_tile: int, n_routers: int) -> int:
-    """Largest divisor of ``n_routers`` <= ``router_tile`` (0 = whole fabric).
+    """Routers per program: the largest multiple of 8 that divides
+    ``n_routers`` and is <= ``router_tile``; the whole fabric when there
+    is none or ``router_tile`` is 0 or >= ``n_routers``.
 
-    Snapping to a divisor keeps every block full (no padding programs, no
-    masked lanes) while honoring the requested tile as an upper bound.
+    Dividing keeps every block full (no padding programs, no masked
+    lanes); the multiple of 8 is what the TPU accepts as the
+    second-to-last block dim of the ``[C, R, P]`` counters.
     """
-    if router_tile <= 0 or router_tile >= n_routers:
-        return n_routers
-    k = router_tile
-    while n_routers % k:
-        k -= 1
-    return k
+    if 0 < router_tile < n_routers:
+        for k in range(router_tile - router_tile % 8, 0, -8):
+            if n_routers % k == 0:
+                return k
+    return n_routers
 
 
-def _arb_kernel(in_buf_ref, in_cnt_ref, out_cnt_ref, rr_ref, wh_ref, route_ref,
-                arb_pop_ref, granted_ref, chosen_ref, rr_out_ref, wh_out_ref,
-                in_space_ref, *, depth_out: int):
+def _arb_kernel(heads_ref, req_ref, in_cnt_ref, out_cnt_ref, rr_ref, wh_ref,
+                *out_refs, depth_in: int, depth_out: int):
     """Arbitration decisions for one (channel, K-router block) program."""
-    arb = ref.arb_decisions(
-        in_buf_ref[0],  # [K, P, Din, NF]
-        in_cnt_ref[0],  # [K, P]
-        out_cnt_ref[0],
-        rr_ref[0],
-        wh_ref[0],
-        route_ref[...],  # [K, E]
-        depth_out=depth_out,
-    )
-    arb_pop_ref[...] = arb.arb_pop[None]
-    granted_ref[...] = arb.granted[None]
-    chosen_ref[...] = arb.chosen[None]
-    rr_out_ref[...] = arb.rr_ptr[None]
-    wh_out_ref[...] = arb.wh_lock[None]
-    in_space_ref[...] = arb.in_space[None]
+    arb = ref.arbitrate(req_ref[0], heads_ref[0], in_cnt_ref[0],
+                        out_cnt_ref[0], rr_ref[0], wh_ref[0],
+                        depth_in=depth_in, depth_out=depth_out)
+    for out_ref, val in zip(out_refs, arb):
+        out_ref[...] = val[None]
 
 
-def _arb_kernel_vc(in_buf_ref, in_cnt_ref, out_cnt_ref, rr_ref, wh_ref,
-                   route_ref, vc_out_ref, arb_pop_ref, granted_ref,
-                   chosen_ref, rr_out_ref, wh_out_ref, in_space_ref,
-                   *, depth_out: int, n_vcs: int):
-    """VC-aware arbitration: the routing table's physical out port expands
-    to an output slot via the block's ``vc_out`` rows (dateline switching).
-    Separate from ``_arb_kernel`` so the default path's trace — pinned
-    bit-identical by the golden tests — carries no extra operand."""
-    arb = ref.arb_decisions(
-        in_buf_ref[0],  # [K, PV, Din, NF]
-        in_cnt_ref[0],  # [K, PV]
-        out_cnt_ref[0],
-        rr_ref[0],
-        wh_ref[0],
-        route_ref[...],  # [K, E]
-        depth_out=depth_out,
-        vc_out=vc_out_ref[...],  # [K, PV, Pp]
-        n_vcs=n_vcs,
-    )
-    arb_pop_ref[...] = arb.arb_pop[None]
-    granted_ref[...] = arb.granted[None]
-    chosen_ref[...] = arb.chosen[None]
-    rr_out_ref[...] = arb.rr_ptr[None]
-    wh_out_ref[...] = arb.wh_lock[None]
-    in_space_ref[...] = arb.in_space[None]
-
-
-def _arb_kernel_offload(*refs, depth_out: int, n_endpoints: int, n_vcs: int,
-                        has_vc: bool):
+def _arb_kernel_offload(heads_ref, req_ref, in_cnt_ref, out_cnt_ref, rr_ref,
+                        wh_ref, fork_ref, rparent_ref, rneed_ref, racc_ref,
+                        rgot_ref, *out_refs, depth_in: int, depth_out: int,
+                        n_endpoints: int):
     """Collective-offload arbitration: fork table + reduction ALU.
 
     Mirrors ``ref.offload_decisions`` for one (channel, K-router block)
     program; the per-(router, group) reduction accumulator/contribution
     state rides as two extra channel-batched operands and comes back as two
-    extra outputs. Separate from ``_arb_kernel``/``_arb_kernel_vc`` so the
-    default paths' traces — pinned bit-identical by the golden tests —
-    carry no extra operands. The apply kernel is shared unchanged: fork
-    copies and emitted reduction flits arrive through the merged
-    grant/chosen decisions.
+    extra outputs. The apply kernel is shared unchanged: fork copies and
+    emitted reduction flits arrive through the merged grant/chosen
+    decisions.
     """
-    if has_vc:
-        (in_buf_ref, in_cnt_ref, out_cnt_ref, rr_ref, wh_ref, route_ref,
-         vc_out_ref, fork_ref, rparent_ref, rneed_ref, racc_ref, rgot_ref,
-         arb_pop_ref, granted_ref, chosen_ref, rr_out_ref, wh_out_ref,
-         in_space_ref, racc_out_ref, rgot_out_ref) = refs
-        vc_out = vc_out_ref[...]
-    else:
-        (in_buf_ref, in_cnt_ref, out_cnt_ref, rr_ref, wh_ref, route_ref,
-         fork_ref, rparent_ref, rneed_ref, racc_ref, rgot_ref,
-         arb_pop_ref, granted_ref, chosen_ref, rr_out_ref, wh_out_ref,
-         in_space_ref, racc_out_ref, rgot_out_ref) = refs
-        vc_out = None
     arb, racc2, rgot2 = ref.offload_decisions(
-        in_buf_ref[0],  # [K, P, Din, NF]
-        in_cnt_ref[0],  # [K, P]
-        out_cnt_ref[0],
-        rr_ref[0],
-        wh_ref[0],
-        route_ref[...],  # [K, E]
-        depth_out=depth_out,
+        req_ref[0], heads_ref[0], in_cnt_ref[0], out_cnt_ref[0], rr_ref[0],
+        wh_ref[0], depth_in=depth_in, depth_out=depth_out,
         fork_out=fork_ref[...],  # [K, NG, P]
         red_parent=rparent_ref[...],  # [K, NG]
         red_need=rneed_ref[...],  # [K, NG]
         red_acc=racc_ref[0],  # [K, NG, NRED]
         red_got=rgot_ref[0],  # [K, NG, P]
-        n_endpoints=n_endpoints,
-        vc_out=vc_out,
-        n_vcs=n_vcs,
-    )
-    arb_pop_ref[...] = arb.arb_pop[None]
-    granted_ref[...] = arb.granted[None]
-    chosen_ref[...] = arb.chosen[None]
-    rr_out_ref[...] = arb.rr_ptr[None]
-    wh_out_ref[...] = arb.wh_lock[None]
-    in_space_ref[...] = arb.in_space[None]
-    racc_out_ref[...] = racc2[None]
-    rgot_out_ref[...] = rgot2[None]
+        n_endpoints=n_endpoints)
+    for out_ref, val in zip(out_refs, (*arb, racc2, rgot2)):
+        out_ref[...] = val[None]
 
 
 def _apply_kernel(in_buf_ref, in_cnt_ref, out_buf_ref, out_cnt_ref,
-                  arb_pop_ref, granted_ref, chosen_ref, in_space_ref,
-                  out_heads_all_ref, out_valid_all_ref, in_space_all_ref,
-                  link_src_ref, link_dst_ref, port_ep_ref, ep_space_ref,
-                  new_in_buf_ref, new_in_cnt_ref, new_out_buf_ref,
-                  new_out_cnt_ref, *, fused: bool, n_vcs: int = 1):
-    """Link resolution + FIFO update for one (channel, K-block) program."""
-    in_buf = in_buf_ref[0]  # [K, P, Din, NF]
-    in_cnt = in_cnt_ref[0]  # [K, P]
-    out_buf = out_buf_ref[0]  # [K, P, Dout, NF]
-    out_cnt = out_cnt_ref[0]
-
-    up_head, link_accept = ref.link_inputs(
-        out_heads_all_ref[0],  # [R, P, NF] full-fabric snapshot
-        out_valid_all_ref[0],  # [R, P]
-        link_src_ref[...],  # [K, Pp, 2] own upstream table rows
-        in_space_ref[0],  # [K, P] own post-pop input space
-        n_vcs=n_vcs,
-    )
-    sent = ref.sent_mask(
-        out_cnt > 0,  # [K, P] own output-head validity
-        link_dst_ref[...],  # [K, Pp, 2]
-        port_ep_ref[...],  # [K, P]
-        in_space_all_ref[0],  # [R, P] downstream space, fabric-wide
-        ep_space_ref[0],  # [E] endpoint ingress space, this channel
-        n_vcs=n_vcs,
-    )
-    in2, in_cnt2, out2, out_cnt2 = ref.apply_cycle(
-        in_buf, in_cnt, out_buf, out_cnt,
-        arb_pop_ref[0], granted_ref[0], chosen_ref[0],
-        link_accept, up_head, sent, fused=fused)
-    new_in_buf_ref[...] = in2[None]
-    new_in_cnt_ref[...] = in_cnt2[None]
-    new_out_buf_ref[...] = out2[None]
-    new_out_cnt_ref[...] = out_cnt2[None]
+                  arb_pop_ref, granted_ref, chosen_ref, accept_ref,
+                  up_head_ref, sent_ref, *out_refs, fused: bool):
+    """FIFO pops/pushes for one (channel, K-block) program."""
+    new = ref.apply_cycle(
+        in_buf_ref[0], in_cnt_ref[0], out_buf_ref[0], out_cnt_ref[0],
+        arb_pop_ref[0], granted_ref[0], chosen_ref[0], accept_ref[0],
+        up_head_ref[0], sent_ref[0], fused=fused)
+    for out_ref, val in zip(out_refs, new):
+        out_ref[...] = val[None]
 
 
 def router_cycle_pallas(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
@@ -220,14 +142,15 @@ def router_cycle_pallas(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
     ``link_src``/``link_dst`` [R, Pp, 2], ``port_ep`` [R, P], ``ep_attach``
     [E, 2]); ``ep_space`` [C, E] is the per-channel endpoint ingress-space
     mask. ``router_tile`` blocks K routers per program (grid
-    ``(C, R / K)``); ``fused_fifo`` selects the fused FIFO datapath (must
-    match the jnp side being compared against). With ``n_vcs > 1`` the
-    state P axis is slot-level (physical ports Pp = P / n_vcs; link tables
-    stay physical) and the arb kernel additionally reads the block's
-    ``vc_out`` [R, P, Pp] rows. Returns the updated state plus the
-    endpoint deliveries ``(ep_flit [C, E, NF], ep_valid [C, E])`` —
-    identical, bit for bit, to ``ref.router_cycle_reference`` vmapped over
-    channels with the same ``fused`` flag.
+    ``(C, R / K)``, see ``effective_tile``); ``fused_fifo`` selects the
+    fused FIFO datapath (must match the jnp side being compared against).
+    With ``n_vcs > 1`` the state P axis is slot-level (physical ports
+    Pp = P / n_vcs; link tables stay physical) and the request lookup
+    expands through ``vc_out`` [R, P, Pp]; the kernels are the same.
+    Returns the updated state plus the endpoint deliveries
+    ``(ep_flit [C, E, NF], ep_valid [C, E])`` — identical, bit for bit, to
+    ``ref.router_cycle_reference`` vmapped over channels with the same
+    ``fused`` flag.
 
     With ``fork_out`` set (collective offload), arbitration runs the
     ``_arb_kernel_offload`` variant: the multicast fork / reduction-tree
@@ -240,58 +163,50 @@ def router_cycle_pallas(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
     C, R, P = in_cnt.shape
     Din = in_buf.shape[-2]
     Dout = out_buf.shape[-2]
-    E = ep_space.shape[-1]
-    Pp = P // n_vcs  # physical ports per router (== P when n_vcs == 1)
     i32 = jnp.int32
     K = effective_tile(router_tile, R)
     G = R // K
 
     state_spec = lambda *tail: pl.BlockSpec(
         (1, K, *tail), lambda c, r: (c, r) + (0,) * len(tail))
-    chan_spec = lambda *tail: pl.BlockSpec(
-        (1, *tail), lambda c, r: (c,) + (0,) * len(tail))
     router_spec = lambda *tail: pl.BlockSpec(
         (K, *tail), lambda c, r: (r,) + (0,) * len(tail))
+    mask = jax.ShapeDtypeStruct((C, R, P), jnp.bool_)
+
+    in_heads = in_buf[..., 0, :]  # [C, R, P, NF]
+    req_port = jax.vmap(functools.partial(
+        ref.request_ports, route=route, vc_out=vc_out, n_vcs=n_vcs))(
+            in_heads, in_cnt)
 
     offload = fork_out is not None
     if offload:
         NG = red_need.shape[-1]
-        arb_fn = functools.partial(_arb_kernel_offload, depth_out=Dout,
-                                   n_endpoints=n_endpoints, n_vcs=n_vcs,
-                                   has_vc=n_vcs > 1)
-        arb_tables = [route] + ([vc_out] if n_vcs > 1 else []) + [
-            fork_out, red_parent, red_need, red_acc, red_got]
-        arb_table_specs = (
-            [router_spec(E)]
-            + ([router_spec(P, Pp)] if n_vcs > 1 else [])
-            + [router_spec(NG, P), router_spec(NG), router_spec(NG),
-               state_spec(NG, NRED), state_spec(NG, P)])
+        arb_fn = functools.partial(_arb_kernel_offload, depth_in=Din,
+                                   depth_out=Dout, n_endpoints=n_endpoints)
+        arb_extra = [fork_out, red_parent, red_need, red_acc, red_got]
+        arb_extra_specs = [router_spec(NG, P), router_spec(NG),
+                           router_spec(NG), state_spec(NG, NRED),
+                           state_spec(NG, P)]
         extra_out_specs = [state_spec(NG, NRED), state_spec(NG, P)]
         extra_out_shapes = [
             jax.ShapeDtypeStruct((C, R, NG, NRED), i32),
             jax.ShapeDtypeStruct((C, R, NG, P), jnp.bool_),
         ]
-    elif n_vcs == 1:
-        arb_fn = functools.partial(_arb_kernel, depth_out=Dout)
-        arb_tables = [route]
-        arb_table_specs = [router_spec(E)]
-        extra_out_specs, extra_out_shapes = [], []
     else:
-        arb_fn = functools.partial(_arb_kernel_vc, depth_out=Dout,
-                                   n_vcs=n_vcs)
-        arb_tables = [route, vc_out]
-        arb_table_specs = [router_spec(E), router_spec(P, Pp)]
+        arb_fn = functools.partial(_arb_kernel, depth_in=Din, depth_out=Dout)
+        arb_extra, arb_extra_specs = [], []
         extra_out_specs, extra_out_shapes = [], []
     arb_pop, granted, chosen, rr2, wh2, in_space, *red_new = pl.pallas_call(
         arb_fn,
         grid=(C, G),
         in_specs=[
-            state_spec(P, Din, NF),  # in_buf
+            state_spec(P, NF),  # input heads
+            state_spec(P),  # requested output slot
             state_spec(P),  # in_cnt
             state_spec(P),  # out_cnt
             state_spec(P),  # rr_ptr
             state_spec(P),  # wh_lock
-            *arb_table_specs,  # route (+ vc_out / offload tables + state)
+            *arb_extra_specs,  # offload tables + reduction state
         ],
         out_specs=[
             state_spec(P),  # arb_pop
@@ -303,23 +218,24 @@ def router_cycle_pallas(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
             *extra_out_specs,  # red_acc' / red_got' (offload only)
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((C, R, P), jnp.bool_),
-            jax.ShapeDtypeStruct((C, R, P), jnp.bool_),
+            mask,
+            mask,
             jax.ShapeDtypeStruct((C, R, P, NF), i32),
             jax.ShapeDtypeStruct((C, R, P), i32),
             jax.ShapeDtypeStruct((C, R, P), i32),
-            jax.ShapeDtypeStruct((C, R, P), jnp.bool_),
+            mask,
             *extra_out_shapes,
         ],
         interpret=interpret,
-    )(in_buf, in_cnt, out_cnt, rr_ptr, wh_lock, *arb_tables)
+    )(in_heads, req_port, in_cnt, out_cnt, rr_ptr, wh_lock, *arb_extra)
 
-    # fabric-wide snapshot views (cycle-start state, untouched by kernel 1)
-    out_heads = out_buf[..., 0, :]  # [C, R, P, NF]
-    out_valid = out_cnt > 0  # [C, R, P]
+    up_head, link_accept, sent, ep_flit, ep_valid = jax.vmap(
+        lambda ob, oc, sp, es: ref.link_stage(
+            ob, oc, sp, link_src, link_dst, port_ep, ep_attach, es,
+            n_vcs=n_vcs))(out_buf, out_cnt, in_space, ep_space)
 
     in2, in_cnt2, out2, out_cnt2 = pl.pallas_call(
-        functools.partial(_apply_kernel, fused=fused_fifo, n_vcs=n_vcs),
+        functools.partial(_apply_kernel, fused=fused_fifo),
         grid=(C, G),
         in_specs=[
             state_spec(P, Din, NF),  # in_buf
@@ -329,14 +245,9 @@ def router_cycle_pallas(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
             state_spec(P),  # arb_pop
             state_spec(P),  # granted
             state_spec(P, NF),  # chosen
-            state_spec(P),  # in_space (own rows)
-            chan_spec(R, P, NF),  # out_heads, full fabric
-            chan_spec(R, P),  # out_valid, full fabric
-            chan_spec(R, P),  # in_space, full fabric
-            router_spec(Pp, 2),  # link_src (physical ports)
-            router_spec(Pp, 2),  # link_dst
-            router_spec(P),  # port_ep (slot-level)
-            chan_spec(E),  # ep_space
+            state_spec(P),  # link_accept
+            state_spec(P, NF),  # up_head
+            state_spec(P),  # sent
         ],
         out_specs=[
             state_spec(P, Din, NF),
@@ -351,13 +262,9 @@ def router_cycle_pallas(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
             jax.ShapeDtypeStruct((C, R, P), i32),
         ],
         interpret=interpret,
-    )(in_buf, in_cnt, out_buf, out_cnt, arb_pop, granted, chosen, in_space,
-      out_heads, out_valid, in_space, link_src, link_dst, port_ep, ep_space)
+    )(in_buf, in_cnt, out_buf, out_cnt, arb_pop, granted, chosen,
+      link_accept, up_head, sent)
 
-    # endpoint deliveries are a pure gather from the cycle-start snapshot
-    er, ep_p = ep_attach[:, 0], ep_attach[:, 1]
-    ep_flit = out_heads[:, er, ep_p]  # [C, E, NF]
-    ep_valid = out_valid[:, er, ep_p] & ep_space
     if offload:
         return (in2, in_cnt2, out2, out_cnt2, rr2, wh2, ep_flit, ep_valid,
                 red_new[0], red_new[1])
@@ -396,11 +303,10 @@ def _fused_impl(in_buf_ref, in_cnt_ref, out_buf_ref, out_cnt_ref, rr_ref,
         carry, (ep_flit, ep_valid, waiting) = ref.fused_cycle_body(
             i, carry, route, link_src, link_dst, port_ep, ep_attach,
             ep_space, cycle0, n_cycles, vc_out=vc_out, n_vcs=n_vcs)
-        sl = (pl.dslice(0, 1), pl.dslice(i, 1))
-        pl.store(deliver_f_ref, (*sl, slice(None), slice(None)),
-                 ep_flit[None, None])
-        pl.store(deliver_v_ref, (*sl, slice(None)), ep_valid[None, None])
-        pl.store(waiting_ref, (*sl, slice(None)), waiting[None, None])
+        at = (slice(0, 1), pl.ds(i, 1))
+        deliver_f_ref[at] = ep_flit[None, None]
+        deliver_v_ref[at] = ep_valid[None, None]
+        waiting_ref[at] = waiting[None, None]
         return carry
 
     carry = jax.lax.fori_loop(0, n_cycles, body, carry)

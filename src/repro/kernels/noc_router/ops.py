@@ -4,8 +4,8 @@
 channel-batched fabric on raw arrays. ``"jnp"`` vmaps the reference
 implementation over the channel axis (the engine's historical hot path);
 ``"pallas"`` launches the (C, R/K)-gridded kernels (``router_tile``
-routers per program), interpreted off-TPU (so CPU CI exercises the exact
-kernel dataflow) and compiled on TPU. Both backends execute the same
+routers per program): compiled on a TPU, interpreted elsewhere, so CPU CI
+exercises the exact kernel dataflow. Both backends execute the same
 decision functions from ``ref.py`` and are bit-identical — pinned by
 ``tests/test_noc_backend.py``. ``fused_fifo`` selects the fused FIFO
 datapath on both backends (identical live contents either way; the flag
@@ -16,11 +16,12 @@ endpoint egress injection threaded through (the multi-cycle super-step):
 ``"jnp"`` scans ``ref.fused_cycle_body``, ``"pallas"`` runs the same body
 inside one kernel per channel with the state resident across the loop.
 
-Caveat: only the interpret path is exercised by CI (this container is
-CPU-only, like the repo's other Pallas kernels). The native TPU lowering
-follows the same ``interpret=None -> auto`` idiom as ``rmsnorm``/``ssd``
-but is not yet covered by a hardware test; pass ``interpret=True``
-explicitly to force the verified path on TPU.
+On a TPU the per-cycle kernels compile for every datapath: default, VC
+and collective offload (``tests/test_tpu_compile.py`` compiles them for a
+described v5e). The fused multi-cycle kernel does not: with ``interpret``
+False it raises ``NotImplementedError`` naming what the TPU compiler
+refuses. Nothing falls back to interpret mode or to the jnp path on a
+TPU.
 
 This module is deliberately free of ``repro.core.noc`` imports: the engine
 layers on top of it, not the other way around.
@@ -32,6 +33,7 @@ import functools
 import jax
 
 from repro.kernels.noc_router.noc_router import (
+    FUSED_TPU_REFUSAL,
     router_cycle_pallas,
     router_cycles_fused_pallas,
 )
@@ -56,6 +58,7 @@ _cycle_jnp_fused = jax.vmap(
 
 
 def _interp(interpret):
+    """Interpret mode off the TPU only (``None`` = decide from the device)."""
     return (jax.default_backend() != "tpu") if interpret is None else interpret
 
 
@@ -162,10 +165,12 @@ def router_cycles_fused(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
             ep_space, cycle0, n_cycles)
         return (*carry, ep_flit, ep_valid, waiting)
     if backend == "pallas":
+        if not _interp(interpret):
+            raise NotImplementedError(FUSED_TPU_REFUSAL)
         return router_cycles_fused_pallas(
             in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
             eg, eg_ready, eg_head, eg_cnt,
             route, link_src, link_dst, port_ep, ep_attach,
-            ep_space, cycle0, n_cycles, interpret=_interp(interpret),
+            ep_space, cycle0, n_cycles, interpret=True,
             vc_out=vc_out, n_vcs=n_vcs)
     raise ValueError(f"unknown router backend {backend!r}; expected one of {BACKENDS}")

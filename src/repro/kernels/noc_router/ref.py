@@ -11,12 +11,18 @@ position relative to that leading axis, so the same code runs on
 
 * the full fabric (``R`` = all routers) — the ``backend="jnp"`` engine path,
   vmapped over channels by ``repro.core.noc.engine``; and
-* a single-router block (``R`` = 1) — inside the Pallas kernel
-  (``repro.kernels.noc_router.noc_router``), gridded over ``(C, R)``.
+* a K-router block — inside the Pallas kernels
+  (``repro.kernels.noc_router.noc_router``), gridded over ``(C, R / K)``.
+  The kernels run the router-local stages (``arbitrate`` /
+  ``offload_decisions`` and ``apply_cycle``); the lookups that gather
+  across routers or tables (``request_ports``, ``link_stage``) run in XLA
+  between them.
 
 Because both backends execute these exact functions on the same integer
 state, they are bit-identical by construction; the golden-pin tests in
-``tests/test_noc_backend.py`` verify it end to end.
+``tests/test_noc_backend.py`` verify it end to end. The kernel-side
+functions use only what the TPU compiler (Mosaic) lowers: selects over
+small static axes instead of gathers, and masks reshaped as int32.
 
 Cycle semantics contract: arbitration and link decisions are both computed
 from the cycle-start snapshot, then applied. A flit therefore spends >= 1
@@ -66,10 +72,18 @@ def pack_flit(dst, src, kind, txn, last, ts, meta) -> jnp.ndarray:
     return jnp.stack(parts, axis=-1)
 
 
+def _expand(mask, axis: int = -1):
+    """``jnp.expand_dims(mask, axis)``, expanded as int32: Mosaic reshapes
+    32-bit vectors but not boolean ones."""
+    return jnp.expand_dims(mask.astype(jnp.int32), axis) > 0
+
+
 def fifo_pop(buf: jnp.ndarray, cnt, pop_mask):
     """Drop the head slot of every FIFO selected by ``pop_mask`` [..., P]."""
-    shifted = jnp.roll(buf, -1, axis=-2)
-    newbuf = jnp.where(pop_mask[..., None, None], shifted, buf)
+    D = buf.shape[-2]
+    pop = _expand(pop_mask)
+    newbuf = jnp.stack([jnp.where(pop, buf[..., (d + 1) % D, :], buf[..., d, :])
+                        for d in range(D)], axis=-2)
     return newbuf, cnt - pop_mask.astype(jnp.int32)
 
 
@@ -77,14 +91,14 @@ def fifo_push(buf: jnp.ndarray, cnt, push_mask, flit: jnp.ndarray):
     """Append ``flit`` [..., P, NF] at the tail where ``push_mask`` [..., P]."""
     D = buf.shape[-2]
     idx = jnp.clip(cnt, 0, D - 1)
-    onehot = jax.nn.one_hot(idx, D, dtype=jnp.bool_) & push_mask[..., None]
-    newbuf = jnp.where(onehot[..., None], flit[..., None, :], buf)
+    newbuf = jnp.stack([jnp.where(_expand(push_mask & (idx == d)), flit,
+                                  buf[..., d, :]) for d in range(D)], axis=-2)
     return newbuf, cnt + push_mask.astype(jnp.int32)
 
 
 def fifo_update(buf: jnp.ndarray, cnt, pop_mask, push_mask, flit: jnp.ndarray):
-    """Fused pop-then-push: one gather + one select instead of a roll, a
-    one-hot and two full-buffer writes.
+    """Fused pop-then-push: each slot is written once by two selects
+    instead of a roll, a one-hot and two full-buffer writes.
 
     Identical to ``fifo_pop`` followed by ``fifo_push`` on every *live* slot
     (index < count); dead slots may hold different garbage than the two-step
@@ -92,27 +106,19 @@ def fifo_update(buf: jnp.ndarray, cnt, pop_mask, push_mask, flit: jnp.ndarray):
     keeps the two-step functions and equivalence is compared through
     ``sim.canonical_state``. Never pushes past the last slot: callers
     guarantee space (``link_accept`` requires ``in_space``; ``granted``
-    requires output-buffer room).
+    requires output-buffer room). Slots are unrolled over the (static,
+    small) depth axis and every select is at most 3-D, which is what
+    Mosaic lowers: no gather and no 4-D broadcast.
     """
     D = buf.shape[-2]
-    d = jnp.arange(D)
     cnt1 = cnt - pop_mask.astype(jnp.int32)
-    if D == 2:
-        # depth-2 FIFOs (the default in/out buffers): write each slot with
-        # one direct select instead of shift-then-mask; one full-buffer
-        # materialization instead of two. Same result as the general path.
-        head = jnp.where(pop_mask[..., None], buf[..., 1, :], buf[..., 0, :])
-        tail = jnp.clip(cnt1, 0, 1)
-        s0 = jnp.where((push_mask & (tail == 0))[..., None], flit, head)
-        s1 = jnp.where((push_mask & (tail == 1))[..., None], flit,
-                       buf[..., 1, :])
-        newbuf = jnp.stack([s0, s1], axis=-2)
-        return newbuf, cnt1 + push_mask.astype(jnp.int32)
-    src = jnp.minimum(d + pop_mask[..., None].astype(jnp.int32), D - 1)
-    shifted = jnp.take_along_axis(buf, src[..., None], axis=-2)
-    at_tail = push_mask[..., None] & (d == jnp.clip(cnt1, 0, D - 1)[..., None])
-    newbuf = jnp.where(at_tail[..., None], flit[..., None, :], shifted)
-    return newbuf, cnt1 + push_mask.astype(jnp.int32)
+    tail = jnp.clip(cnt1, 0, D - 1)
+    pop = _expand(pop_mask)
+    slots = []
+    for d in range(D):
+        s = jnp.where(pop, buf[..., min(d + 1, D - 1), :], buf[..., d, :])
+        slots.append(jnp.where(_expand(push_mask & (tail == d)), flit, s))
+    return jnp.stack(slots, axis=-2), cnt1 + push_mask.astype(jnp.int32)
 
 
 def heads(buf: jnp.ndarray) -> jnp.ndarray:
@@ -124,7 +130,7 @@ class ArbDecisions(NamedTuple):
     """Per-output-port arbitration results, all computed from the snapshot.
 
     All leaves carry the [R, P] leading shape of the inputs (R may be a
-    1-sized Pallas block).
+    K-router Pallas block).
     """
 
     arb_pop: jnp.ndarray  # [R, P_in] bool: head popped by some output port
@@ -135,62 +141,102 @@ class ArbDecisions(NamedTuple):
     in_space: jnp.ndarray  # [R, P_in] bool: input FIFO has a free slot after pops
 
 
+def request_ports(h, in_cnt, route, vc_out=None, n_vcs: int = 1):
+    """Output slot each input head requests (-1 = no valid head).
+
+    ``h`` [R, P, NF] are the input heads, ``in_cnt`` [R, P] their FIFO
+    counts, ``route`` [R, E] the physical out port per destination. This
+    is the only table lookup of arbitration, a gather over the destination
+    axis; the Pallas backend runs it in XLA ahead of its arbitration
+    kernel (Mosaic has no general gather). Destinations clip into
+    ``[0, E)``: group-addressed collective heads (``F_DST >= E``) never
+    request a unicast port, their arbitration masks them out.
+
+    With ``n_vcs > 1`` the port axis P is *slot*-level (physical port *
+    n_vcs + vc) and ``vc_out`` [R, P, P_phys] assigns the departing VC:
+    the routing table still yields a physical out port, which expands to
+    output slot ``phys * n_vcs + vc_out[r, slot_in, phys]`` (dateline
+    VC-switching).
+    """
+    P = in_cnt.shape[-1]
+    E = route.shape[-1]
+    req_port = jnp.take_along_axis(route, jnp.clip(h[..., F_DST], 0, E - 1),
+                                   axis=1)
+    if n_vcs > 1:
+        Pp = P // n_vcs
+        vout = jnp.take_along_axis(
+            vc_out, jnp.clip(req_port, 0, Pp - 1)[..., None], axis=-1)[..., 0]
+        req_port = req_port * n_vcs + vout
+    return jnp.where(in_cnt > 0, req_port, -1)  # [R, P_in]
+
+
 def arb_decisions(in_buf, in_cnt, out_cnt, rr_ptr, wh_lock, route,
                   depth_out: int, vc_out=None, n_vcs: int = 1) -> ArbDecisions:
     """Round-robin output arbitration from the cycle-start snapshot.
 
     Inputs are single-channel: ``in_buf`` [R, P, Din, NF], counters and
     pointers [R, P], ``route`` [R, E], ``depth_out`` the output-buffer
-    depth. Each output port picks the lowest-scoring eligible input head
-    (round-robin distance from ``rr_ptr``); eligibility requires a head
-    routed to that port, a free or matching wormhole lock, and
-    output-buffer space (no same-cycle fall-through). A granted tail flit
-    releases the wormhole lock; a granted body flit locks the output to its
-    input port.
-
-    With ``n_vcs > 1`` the port axis P is *slot*-level (physical port *
-    n_vcs + vc) and ``vc_out`` [R, P, P_phys] assigns the departing VC:
-    the routing table still yields a physical out port, which expands to
-    output slot ``phys * n_vcs + vc_out[r, slot_in, phys]`` (dateline
-    VC-switching). Arbitration then runs unchanged over slots — each
-    output slot has its own round-robin pointer and wormhole lock, so
-    wormholes on different VCs of one physical link interleave safely.
+    depth. The route lookup (``request_ports``, with the VC expansion for
+    ``n_vcs > 1``) feeds ``arbitrate``; each output slot has its own
+    round-robin pointer and wormhole lock, so wormholes on different VCs
+    of one physical link interleave safely.
     """
-    P = in_cnt.shape[-1]
-    Din = in_buf.shape[-2]
-
     h = heads(in_buf)  # [R, P, NF]
-    h_valid = in_cnt > 0
-    req_port = jnp.take_along_axis(route, jnp.clip(h[..., F_DST], 0, None), axis=1)
-    if n_vcs > 1:
-        Pp = P // n_vcs
-        vout = jnp.take_along_axis(
-            vc_out, jnp.clip(req_port, 0, Pp - 1)[..., None], axis=-1)[..., 0]
-        req_port = req_port * n_vcs + vout
-    req_port = jnp.where(h_valid, req_port, -1)  # [R, P_in]
+    req_port = request_ports(h, in_cnt, route, vc_out=vc_out, n_vcs=n_vcs)
+    return arbitrate(req_port, h, in_cnt, out_cnt, rr_ptr, wh_lock,
+                     depth_in=in_buf.shape[-2], depth_out=depth_out)
 
-    pout = jnp.arange(P)
+
+def _round_robin(elig, rr_ptr, h):
+    """Round-robin pick of every output port from ``elig`` [R, P_in, P_out].
+
+    Each output port takes the eligible input with the lowest round-robin
+    distance from ``rr_ptr`` [R, P_out]. The first-min selection is
+    unrolled over the (static, small) input-port axis: the same winner as
+    ``jnp.argmin`` but ~2x faster on XLA CPU, and the winner's head flit
+    from ``h`` [R, P_in, NF] is selected alongside (no gather). Returns
+    ``(granted [R, P_out], winner [R, P_out], win_onehot [R, P_in, P_out],
+    chosen [R, P_out, NF])``.
+    """
+    P = rr_ptr.shape[-1]
     pin = jnp.arange(P)[None, :, None]
-    elig = req_port[:, :, None] == pout[None, None, :]
-    locked = wh_lock[:, None, :]
-    elig &= (locked < 0) | (locked == pin)
-    elig &= (out_cnt < depth_out)[:, None, :]  # no same-cycle fall-through
-
     score = (pin - rr_ptr[:, None, :]) % P
     score = jnp.where(elig, score, P + 1)
-    # first-min selection unrolled over the (static, small) input-port axis:
-    # identical winner to jnp.argmin(score, axis=1) but ~2x faster on XLA CPU
     best = score[:, 0, :]
     winner = jnp.zeros_like(best)
+    chosen = jnp.broadcast_to(h[:, 0:1, :], h.shape)
     for i in range(1, P):
         si = score[:, i, :]
         better = si < best
         best = jnp.where(better, si, best)
         winner = jnp.where(better, i, winner)
-    granted = best <= P  # [R, P_out]
-    win_onehot = (winner[:, None, :] == pin) & granted[:, None, :]
+        chosen = jnp.where(_expand(better), h[:, i:i + 1, :], chosen)
+    win_onehot = (winner[:, None, :] == pin) & (best[:, None, :] <= P)
+    return best <= P, winner, win_onehot, chosen
+
+
+def arbitrate(req_port, h, in_cnt, out_cnt, rr_ptr, wh_lock, depth_in: int,
+              depth_out: int) -> ArbDecisions:
+    """Arbitration of each router from its own snapshot, no table lookup.
+
+    ``req_port`` [R, P_in] is the output slot each input head requests
+    (``request_ports``), ``h`` [R, P_in, NF] the heads. Each output port
+    picks the lowest-scoring eligible input head (round-robin distance
+    from ``rr_ptr``); eligibility requires a head routed to that port, a
+    free or matching wormhole lock, and output-buffer space (no same-cycle
+    fall-through). A granted tail flit releases the wormhole lock; a
+    granted body flit locks the output to its input port. Everything here
+    is elementwise or a select over the (static, small) port axis, which
+    is what the Pallas arbitration kernel compiles for the TPU.
+    """
+    P = in_cnt.shape[-1]
+    pin = jnp.arange(P)[None, :, None]
+    elig = req_port[:, :, None] == jnp.arange(P)[None, None, :]
+    locked = wh_lock[:, None, :]
+    elig &= (locked < 0) | (locked == pin)
+    elig &= out_cnt[:, None, :] < depth_out  # no same-cycle fall-through
+    granted, winner, win_onehot, chosen = _round_robin(elig, rr_ptr, h)
     arb_pop = jnp.any(win_onehot, axis=2)  # [R, P_in]
-    chosen = jnp.take_along_axis(h, winner[:, :, None], axis=1)  # [R, P_out, NF]
 
     rr = jnp.where(granted, (winner + 1) % P, rr_ptr)
     is_tail = chosen[..., F_LAST] > 0
@@ -198,20 +244,20 @@ def arb_decisions(in_buf, in_cnt, out_cnt, rr_ptr, wh_lock, route,
     wh = jnp.where(granted & is_tail, -1, wh)
 
     # space after this cycle's arb pops (slot freed same cycle is reusable)
-    in_space = (in_cnt - arb_pop.astype(jnp.int32)) < Din
+    in_space = (in_cnt - arb_pop.astype(jnp.int32)) < depth_in
     return ArbDecisions(arb_pop, granted, chosen, rr, wh, in_space)
 
 
-def offload_decisions(in_buf, in_cnt, out_cnt, rr_ptr, wh_lock, route,
-                      depth_out: int, fork_out, red_parent, red_need,
-                      red_acc, red_got, n_endpoints: int,
-                      vc_out=None, n_vcs: int = 1):
+def offload_decisions(req_port, h, in_cnt, out_cnt, rr_ptr, wh_lock,
+                      depth_in: int, depth_out: int, fork_out, red_parent,
+                      red_need, red_acc, red_got, n_endpoints: int):
     """Arbitration with tree-multicast fork + in-fabric reduction ALU.
 
-    The ``collective_offload=True`` counterpart of ``arb_decisions`` (which
-    stays byte-for-byte untouched so the pinned default traces carry no
-    extra operands). Single-channel, rank-generic over the leading router
-    axis like every decision function here. Extra inputs:
+    The ``collective_offload=True`` counterpart of ``arbitrate`` (which
+    stays untouched so the default path carries no extra operands), fed
+    the same ``request_ports`` lookup and heads ``h``. Single-channel,
+    rank-generic over the leading router axis like every decision function
+    here. Extra inputs:
 
     * ``fork_out`` [R, G, P] bool — multicast tree out-slots per group: a
       head with ``F_KIND == KIND_MC`` and ``F_DST == n_endpoints + g``
@@ -242,10 +288,8 @@ def offload_decisions(in_buf, in_cnt, out_cnt, rr_ptr, wh_lock, route,
     kernel.
     """
     P = in_cnt.shape[-1]
-    Din = in_buf.shape[-2]
     G = red_need.shape[-1]
 
-    h = heads(in_buf)  # [R, P, NF]
     h_valid = in_cnt > 0
     kind = h[..., F_KIND]
     dst = h[..., F_DST]
@@ -253,19 +297,34 @@ def offload_decisions(in_buf, in_cnt, out_cnt, rr_ptr, wh_lock, route,
     is_red = h_valid & (kind == KIND_RED)
     g_of = jnp.clip(dst - n_endpoints, 0, G - 1)  # [R, P]
 
+    # one-hot group of every head [R, G, P]: every per-group lookup below
+    # is a select over this small axis (Mosaic has no gather)
+    gsel = g_of[:, None, :] == jnp.arange(G)[None, :, None]
+
     # ---- reduction ALU (all decisions from the cycle-start snapshot) ----
     on_tree = red_need > 0  # [R, G]
     full = on_tree & (red_acc[..., A_CNT] >= red_need)
     parent = jnp.clip(red_parent, 0, P - 1)  # [R, G]
-    parent_free = jnp.take_along_axis(out_cnt < depth_out, parent, axis=1)
-    parent_unlocked = jnp.take_along_axis(wh_lock, parent, axis=1) < 0
+    psel = parent[..., None] == jnp.arange(P)  # [R, G, P_out]
+    parent_free = jnp.any(psel & (out_cnt[:, None, :] < depth_out), axis=-1)
+    parent_unlocked = jnp.any(psel & (wh_lock[:, None, :] < 0), axis=-1)
     can_emit = full & (red_parent >= 0) & parent_free & parent_unlocked
-    emit_oh = (parent[..., None] == jnp.arange(P)) & can_emit[..., None]
-    emit_oh &= jnp.cumsum(emit_oh.astype(jnp.int32), axis=-2) == 1
-    emit_port = jnp.any(emit_oh, axis=-2)  # [R, P_out]
-    emitting = jnp.any(emit_oh, axis=-1)  # [R, G]
-    g_sel = jnp.argmax(emit_oh, axis=-2)  # [R, P_out]
-    acc_sel = jnp.take_along_axis(red_acc, g_sel[..., None], axis=1)
+    # lowest group id wins a shared parent port (rows sliced as int32:
+    # Mosaic cannot slice boolean vectors along the group axis)
+    emit_i = (psel & _expand(can_emit)).astype(jnp.int32)
+    rows = [emit_i[:, 0, :] > 0]
+    taken = rows[0]
+    for g in range(1, G):
+        rows.append((emit_i[:, g, :] > 0) & ~taken)
+        taken |= rows[-1]
+    emit_port = taken  # [R, P_out]
+    emitting = jnp.stack([r.astype(jnp.int32) for r in rows],
+                         axis=1).max(-1) > 0  # [R, G]
+    g_sel = jnp.zeros(emit_port.shape, jnp.int32)  # [R, P_out]
+    acc_sel = jnp.zeros((*emit_port.shape, NRED), jnp.int32)
+    for g, row in enumerate(rows):
+        g_sel = jnp.where(row, g, g_sel)
+        acc_sel = jnp.where(_expand(row), red_acc[:, g:g + 1, :], acc_sel)
     red_flit = pack_flit(  # stays group-addressed for the next hop
         n_endpoints + g_sel, acc_sel[..., A_SRC], KIND_RED,
         acc_sel[..., A_TXN], 1 - acc_sel[..., A_NLAST],
@@ -275,14 +334,13 @@ def offload_decisions(in_buf, in_cnt, out_cnt, rr_ptr, wh_lock, route,
     # not yet contributed to the current beat, and the slot is either not
     # full or flushing its snapshot this same cycle (pipelined refill).
     accept_g = on_tree & (~full | emitting)  # [R, G]
-    accept_at = jnp.take_along_axis(accept_g, g_of, axis=1)  # [R, P]
-    got_at = jnp.take_along_axis(red_got, g_of[:, None, :], axis=1)[:, 0]
+    accept_at = jnp.any(gsel & _expand(accept_g), axis=1)  # [R, P]
+    got_at = jnp.any(gsel & red_got, axis=1)
     red_pop = is_red & ~got_at & accept_at  # [R, P_in]
 
-    gmask = (red_pop[:, None, :]
-             & (g_of[:, None, :] == jnp.arange(G)[None, :, None]))  # [R, G, P]
-    base_acc = jnp.where(emitting[..., None], 0, red_acc)
-    base_got = jnp.where(emitting[..., None], False, red_got)
+    gmask = _expand(red_pop, 1) & gsel  # [R, G, P]
+    base_acc = jnp.where(_expand(emitting), 0, red_acc)
+    base_got = red_got & ~_expand(emitting)
     gm = gmask.astype(jnp.int32)
 
     def _contrib(f, combine):
@@ -304,50 +362,34 @@ def offload_decisions(in_buf, in_cnt, out_cnt, rr_ptr, wh_lock, route,
     red_got2 = base_got | gmask
 
     # ---- arbitration with multicast fork requests -----------------------
-    req_port = jnp.take_along_axis(
-        route, jnp.clip(dst, 0, n_endpoints - 1), axis=1)
-    if n_vcs > 1:
-        Pp = P // n_vcs
-        vout = jnp.take_along_axis(
-            vc_out, jnp.clip(req_port, 0, Pp - 1)[..., None], axis=-1)[..., 0]
-        req_port = req_port * n_vcs + vout
     uni = h_valid & ~is_mc & ~is_red
     req_port = jnp.where(uni, req_port, -1)
 
-    pout = jnp.arange(P)
     pin = jnp.arange(P)[None, :, None]
-    fork_at = jnp.take_along_axis(fork_out, g_of[..., None], axis=1)
-    req = ((req_port[:, :, None] == pout[None, None, :])
-           | (is_mc[:, :, None] & fork_at))  # [R, P_in, P_out]
+    fork_i = fork_out.astype(jnp.int32)
+    fork_at = jnp.zeros((*g_of.shape, P), jnp.int32)  # [R, P_in, P_out]
+    for g in range(G):
+        fork_at = jnp.where(_expand(g_of == g), fork_i[:, g:g + 1, :], fork_at)
+    req = ((req_port[:, :, None] == jnp.arange(P)[None, None, :])
+           | (_expand(is_mc) & (fork_at > 0)))  # [R, P_in, P_out]
     elig = req
     locked = wh_lock[:, None, :]
     elig &= (locked < 0) | (locked == pin)
-    elig &= (out_cnt < depth_out)[:, None, :]
-    elig &= ~emit_port[:, None, :]  # reduction emission owns the port
-
-    score = (pin - rr_ptr[:, None, :]) % P
-    score = jnp.where(elig, score, P + 1)
-    best = score[:, 0, :]
-    winner = jnp.zeros_like(best)
-    for i in range(1, P):
-        si = score[:, i, :]
-        better = si < best
-        best = jnp.where(better, si, best)
-        winner = jnp.where(better, i, winner)
-    granted0 = best <= P  # [R, P_out]
-    win_onehot = (winner[:, None, :] == pin) & granted0[:, None, :]
+    elig &= out_cnt[:, None, :] < depth_out
+    elig &= ~_expand(emit_port, 1)  # reduction emission owns the port
+    granted0, winner, win_onehot, chosen = _round_robin(elig, rr_ptr, h)
 
     # a multicast head fires only when it wins EVERY requested branch
     fire_mc = is_mc & jnp.any(req, axis=2) & ~jnp.any(req & ~win_onehot,
                                                       axis=2)
-    pop_uni = jnp.any(win_onehot & uni[..., None], axis=2)
+    pop_uni = jnp.any(win_onehot & _expand(uni), axis=2)
     arb_pop = pop_uni | fire_mc | red_pop
 
     # cancel grants whose winner is a multicast head that did not fire
-    w_is_mc = jnp.take_along_axis(is_mc, winner, axis=1)
-    w_fired = jnp.take_along_axis(fire_mc, winner, axis=1)
+    win_at = winner[:, None, :] == pin  # [R, P_in, P_out]
+    w_is_mc = jnp.any(win_at & _expand(is_mc), axis=1)
+    w_fired = jnp.any(win_at & _expand(fire_mc), axis=1)
     granted = granted0 & (~w_is_mc | w_fired)
-    chosen = jnp.take_along_axis(h, winner[:, :, None], axis=1)
 
     rr = jnp.where(granted, (winner + 1) % P, rr_ptr)
     is_tail = chosen[..., F_LAST] > 0
@@ -356,9 +398,9 @@ def offload_decisions(in_buf, in_cnt, out_cnt, rr_ptr, wh_lock, route,
 
     # merge reduction emissions (their ports were excluded from arb)
     granted_all = granted | emit_port
-    chosen_all = jnp.where(emit_port[..., None], red_flit, chosen)
+    chosen_all = jnp.where(_expand(emit_port), red_flit, chosen)
 
-    in_space = (in_cnt - arb_pop.astype(jnp.int32)) < Din
+    in_space = (in_cnt - arb_pop.astype(jnp.int32)) < depth_in
     return (ArbDecisions(arb_pop, granted_all, chosen_all, rr, wh, in_space),
             red_acc2, red_got2)
 
@@ -453,6 +495,31 @@ def sent_mask(out_valid, link_dst, port_ep, in_space_all, ep_space,
     return sent_link | sent_ep
 
 
+def link_stage(out_buf, out_cnt, in_space, link_src, link_dst, port_ep,
+               ep_attach, ep_space, n_vcs: int = 1):
+    """Every cross-router decision of the cycle for one channel.
+
+    From the cycle-start output buffers ``out_buf`` [R, P, Dout, NF] /
+    ``out_cnt`` [R, P] and the post-pop input space ``in_space`` [R, P] of
+    every router, returns ``(up_head [R, P, NF], link_accept [R, P],
+    sent [R, P], ep_flit [E, NF], ep_valid [E])``: what each input port
+    takes off its link, which output heads leave, and the endpoint
+    deliveries. These are the gathers over the router axis that the link
+    wiring implies; the Pallas backend runs this stage in XLA between its
+    arbitration and apply kernels.
+    """
+    out_heads = heads(out_buf)
+    out_valid = out_cnt > 0
+    up_head, link_accept = link_inputs(out_heads, out_valid, link_src,
+                                       in_space, n_vcs=n_vcs)
+    sent = sent_mask(out_valid, link_dst, port_ep, in_space, ep_space,
+                     n_vcs=n_vcs)
+    er, ep_p = ep_attach[:, 0], ep_attach[:, 1]
+    ep_flit = out_heads[er, ep_p]  # [E, NF]
+    ep_valid = out_valid[er, ep_p] & ep_space
+    return up_head, link_accept, sent, ep_flit, ep_valid
+
+
 def apply_cycle(in_buf, in_cnt, out_buf, out_cnt, arb_pop, granted, chosen,
                 link_accept, up_head, sent, fused: bool = False):
     """Apply the snapshot decisions: FIFO pops then pushes, per side.
@@ -490,21 +557,12 @@ def router_cycle_reference(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
     arb = arb_decisions(in_buf, in_cnt, out_cnt, rr_ptr, wh_lock, route,
                         depth_out=out_buf.shape[-2], vc_out=vc_out,
                         n_vcs=n_vcs)
-
-    out_heads = heads(out_buf)
-    out_valid = out_cnt > 0
-    up_head, link_accept = link_inputs(out_heads, out_valid, link_src,
-                                       arb.in_space, n_vcs=n_vcs)
-    sent = sent_mask(out_valid, link_dst, port_ep, arb.in_space, ep_space,
-                     n_vcs=n_vcs)
-
+    up_head, link_accept, sent, ep_flit, ep_valid = link_stage(
+        out_buf, out_cnt, arb.in_space, link_src, link_dst, port_ep,
+        ep_attach, ep_space, n_vcs=n_vcs)
     in2, in_cnt2, out2, out_cnt2 = apply_cycle(
         in_buf, in_cnt, out_buf, out_cnt, arb.arb_pop, arb.granted, arb.chosen,
         link_accept, up_head, sent, fused=fused)
-
-    er, ep_p = ep_attach[:, 0], ep_attach[:, 1]
-    ep_flit = out_heads[er, ep_p]  # [E, NF]
-    ep_valid = out_valid[er, ep_p] & ep_space
     return in2, in_cnt2, out2, out_cnt2, arb.rr_ptr, arb.wh_lock, ep_flit, ep_valid
 
 
@@ -523,26 +581,19 @@ def router_cycle_offload_reference(in_buf, in_cnt, out_buf, out_cnt, rr_ptr,
     The link-traversal and apply phases are byte-for-byte shared: the
     offload path only changes *which* flits are popped and latched.
     """
+    h = heads(in_buf)
     arb, red_acc2, red_got2 = offload_decisions(
-        in_buf, in_cnt, out_cnt, rr_ptr, wh_lock, route,
+        request_ports(h, in_cnt, route, vc_out=vc_out, n_vcs=n_vcs), h,
+        in_cnt, out_cnt, rr_ptr, wh_lock, depth_in=in_buf.shape[-2],
         depth_out=out_buf.shape[-2], fork_out=fork_out,
         red_parent=red_parent, red_need=red_need, red_acc=red_acc,
-        red_got=red_got, n_endpoints=n_endpoints, vc_out=vc_out, n_vcs=n_vcs)
-
-    out_heads = heads(out_buf)
-    out_valid = out_cnt > 0
-    up_head, link_accept = link_inputs(out_heads, out_valid, link_src,
-                                       arb.in_space, n_vcs=n_vcs)
-    sent = sent_mask(out_valid, link_dst, port_ep, arb.in_space, ep_space,
-                     n_vcs=n_vcs)
-
+        red_got=red_got, n_endpoints=n_endpoints)
+    up_head, link_accept, sent, ep_flit, ep_valid = link_stage(
+        out_buf, out_cnt, arb.in_space, link_src, link_dst, port_ep,
+        ep_attach, ep_space, n_vcs=n_vcs)
     in2, in_cnt2, out2, out_cnt2 = apply_cycle(
         in_buf, in_cnt, out_buf, out_cnt, arb.arb_pop, arb.granted, arb.chosen,
         link_accept, up_head, sent, fused=fused)
-
-    er, ep_p = ep_attach[:, 0], ep_attach[:, 1]
-    ep_flit = out_heads[er, ep_p]  # [E, NF]
-    ep_valid = out_valid[er, ep_p] & ep_space
     return (in2, in_cnt2, out2, out_cnt2, arb.rr_ptr, arb.wh_lock,
             ep_flit, ep_valid, red_acc2, red_got2)
 

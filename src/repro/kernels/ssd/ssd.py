@@ -14,9 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names this TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(x_ref, dt_ref, b_ref, c_ref, alog_ref, d_ref, y_ref, state_sc, *, nc):
     c_idx = pl.program_id(1)
@@ -79,7 +76,7 @@ def ssd_bhqp(x, dt, Bv, Cv, A_log, D, *, chunk: int = 128, interpret: bool = Fal
         out_specs=pl.BlockSpec((1, Q, P), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -26,7 +26,6 @@ import jax
 from repro.configs import SHAPES, get_config, list_archs, shape_applicable
 from repro.launch import roofline as RL
 from repro.launch.mesh import make_production_mesh, make_reduced_mesh
-from repro.runtime import set_mesh
 from repro.launch.specs import (
     decode_input_schema,
     serve_needs_2d,
@@ -125,7 +124,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, reduced: bool = False,
         "status": "ok",
     }
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(fn, in_shardings=shardings, donate_argnums=donate)
             lowered = jitted.lower(*args)
             res["t_lower_s"] = round(time.time() - t0, 2)

@@ -20,7 +20,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.spec import PSpec
-from repro.runtime import Runtime, shard_map
+from repro.runtime import Runtime
 
 
 def moe_schema(cfg: ModelConfig) -> dict:
@@ -237,7 +237,7 @@ def _moe_block_a2a(p, x, *, cfg: ModelConfig, rt: Runtime):
     if "shared" in p:
         pspecs["shared"] = {"w1": P(None, None), "w3": P(None, None), "w2": P(None, None)}
     pspecs["router"] = P(None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs, P(*bspec, None, None)),
         out_specs=(P(*bspec, None, None), P()),
@@ -275,7 +275,7 @@ def moe_block(p, x, *, cfg: ModelConfig, rt: Runtime):
             "w2": P("model", None),
         }
     pspecs["router"] = P(None, None)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(pspecs, P(*bspec, None, None)),

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.attention import combine_partials, decode_attention_partial
-from repro.runtime import axis_size, shard_map
 
 
 def split_kv_decode(q, k_cache, v_cache, cache_len, *, mesh, seq_axes=("data",),
@@ -38,7 +37,7 @@ def split_kv_decode(q, k_cache, v_cache, cache_len, *, mesh, seq_axes=("data",),
         stride = 1
         for a in reversed(seq_axes):
             idx = idx + jax.lax.axis_index(a) * stride
-            stride = stride * axis_size(a)
+            stride = stride * jax.lax.axis_size(a)
         off = idx * S_loc
         kpos = off + jnp.arange(S_loc, dtype=jnp.int32)[None, :]
         valid = kpos < length[:, None]
@@ -46,7 +45,7 @@ def split_kv_decode(q, k_cache, v_cache, cache_len, *, mesh, seq_axes=("data",),
         out = combine_partials(m, l, o, seq_axes if len(seq_axes) > 1 else seq_axes[0])
         return out[:, None].astype(q.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, None, None, None), P(None, seq_axes, None, None),
                   P(None, seq_axes, None, None), P(None)),

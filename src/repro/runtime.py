@@ -4,52 +4,16 @@ shard_map'd collectives (MoE dispatch, split-KV decode, floo gradient sync).
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are Auto-typed implicitly
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
+    """Device mesh with Auto-typed axes (sharding propagated by GSPMD)."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def axis_size(name):
-    """jax.lax.axis_size across jax versions (older jax: psum of 1 over the
-    named axis, constant-folded inside shard_map)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
-def set_mesh(mesh):
-    """jax.set_mesh across jax versions: older jax activates a mesh by using
-    the Mesh object itself as a context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """jax.shard_map across jax versions (older jax spells it
-    jax.experimental.shard_map.shard_map with check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # check_rep=False: the legacy replication checker mishandles symbolic-Zero
-    # cotangents through pmean/psum transposes; it is a static check only, so
-    # disabling it does not change numerics.
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
 
 
 def single_device_mesh() -> jax.sharding.Mesh:

@@ -23,7 +23,7 @@ from repro.data.pipeline import DataConfig, Prefetcher, SyntheticLM
 from repro.models import model as M
 from repro.models.spec import count_params_tree
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update, opt_state_schema
-from repro.runtime import Runtime, set_mesh, shard_map
+from repro.runtime import Runtime
 from repro.sharding.partition import sharding_tree, train_rules
 from repro.train.fault_tolerance import NanGuard, PreemptionHandler, StragglerMonitor
 
@@ -99,7 +99,7 @@ class Trainer:
                 return params, opt_state, {**metrics, **om}
 
             pspec = jax.tree.map(lambda _: P(), self.p_sh)
-            step_fn = shard_map(
+            step_fn = jax.shard_map(
                 local_step, mesh=mesh,
                 in_specs=(pspec, jax.tree.map(lambda _: P(), self.o_sh),
                           P(*self.batch_spec, None)),
@@ -125,7 +125,7 @@ class Trainer:
 
     # ------------------------------------------------------------------
     def init_state(self):
-        with set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             params = jax.jit(
                 lambda k: M.init_params(self.cfg, k), out_shardings=self.p_sh
             )(jax.random.key(self.tcfg.seed))
@@ -154,7 +154,7 @@ class Trainer:
 
         history = []
         last_good = None
-        with set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             for step in range(start, self.tcfg.steps):
                 if self.preempt.requested:
                     if self.ckpt:

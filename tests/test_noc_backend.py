@@ -18,6 +18,7 @@ from repro.core.noc import sim as S
 from repro.core.noc import traffic as T
 from repro.core.noc.params import NocParams
 from repro.core.noc.topology import build_topology
+from repro.kernels.noc_router.noc_router import effective_tile
 from test_noc_channels import GOLDEN, _golden_sim
 
 
@@ -33,19 +34,23 @@ def _assert_states_equal(a, b, tag=""):
                                       err_msg=tag)
 
 
-# one config per zoo topology, paired with a router-tile size K and a
-# fused-super-step width k so every (K, k) axis value is exercised on the
-# zoo without a full cross-product:
-# (name, build kwargs, n_channels, streams, router_tile, fused_cycles).
-# router_tile 0 = whole fabric per program (K=R); fused_cycles > 1 runs
-# k cycles per pallas_call with state resident across the window.
+# zoo configs for the per-cycle tiled kernel and the fused multi-cycle
+# kernel: (name, build kwargs, n_channels, streams, router_tile,
+# fused_cycles). With fused_cycles=1 the grid is (C, R / K) with
+# K = effective_tile(router_tile, R): router_tile 8 splits the 16-, 24- and
+# 32-router fabrics into 2, 3 and 4 blocks whose links cross block edges,
+# router_tile 0 is the whole fabric per program (K = R). fused_cycles > 1
+# runs k cycles per pallas_call, one program per channel (router_tile is
+# unused there), with state resident across the window.
 ZOO = [
-    ("mesh", dict(nx=4, ny=2), 3, 1, 1, 1),
-    ("mesh", dict(nx=4, ny=2), 4, 2, 4, 1),
+    ("mesh", dict(nx=4, ny=4), 3, 1, 8, 1),
+    ("mesh", dict(nx=8, ny=4), 4, 2, 8, 1),
     ("torus", dict(nx=4, ny=2), 3, 1, 0, 1),
     ("torus", dict(nx=4, ny=2), 4, 2, 1, 4),
     ("multi_die", dict(n_dies=2, nx=2, ny=2, d2d=2), 3, 1, 4, 4),
     ("multi_die", dict(n_dies=2, nx=2, ny=2, d2d=2), 4, 2, 0, 4),
+    ("torus", dict(nx=4, ny=4), 4, 2, 8, 1),
+    ("multi_die", dict(n_dies=2, nx=2, ny=4, d2d=2), 3, 1, 8, 1),
 ]
 
 
@@ -57,6 +62,8 @@ def test_pallas_matches_jnp_state_bitexact(name, kw, channels, streams,
     fused multi-cycle kernel (fused_cycles=k) alike — each against the jnp
     reference with the same stepping knobs."""
     topo = build_topology(name, **kw)
+    if fused == 1 and tile:  # the grid really has several router blocks
+        assert effective_tile(tile, topo.n_routers) < topo.n_routers
     wl = T.dma_workload(topo, "uniform", transfer_kb=1, n_txns=2,
                         streams=streams)
     stj = S.run(S.build_sim(
@@ -124,3 +131,14 @@ def test_backend_validation():
 
     with pytest.raises(ValueError):
         ops.router_cycle(*([None] * 12), backend="nope")
+
+
+def test_fused_pallas_refused_on_tpu():
+    """The fused multi-cycle kernel does not compile for the TPU: asked to
+    run compiled, it raises with the compiler's reason instead of falling
+    back to interpret mode or to the jnp path."""
+    from repro.kernels.noc_router import ops
+
+    with pytest.raises(NotImplementedError, match="does not compile for TPU"):
+        ops.router_cycles_fused(*([None] * 17), 4, backend="pallas",
+                                interpret=False)

@@ -190,6 +190,25 @@ def test_frontier_artifact_deterministic(dse_smoke):
     assert all(r["delivered"] for r in rows)  # budgets sized to finish
 
 
+def test_run_dse_one_process_on_accelerator(monkeypatch):
+    """Off the CPU backend the process that holds the devices is the only
+    one: workers=None runs in-process and workers>1 raises."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr("os.cpu_count", lambda: 8)
+
+    def no_pool(*args, **kwargs):
+        raise AssertionError("run_dse started a worker pool")
+
+    monkeypatch.setattr(dse, "_run_dse_pool", no_pool)
+    specs = dse.default_grid(smoke=True)
+    with pytest.raises(ValueError, match="workers=2"):
+        dse.run_dse(specs, workers=2)
+    results = dse.run_dse(specs, workers=None)
+    assert len(results) == len(specs) and all(r["delivered"] for r in results)
+
+
 def test_run_dse_requires_workload_binding():
     with pytest.raises(ValueError, match="workload binding"):
         dse.run_dse([preset("mesh")])

@@ -90,7 +90,7 @@ def test_live_trip_multiplication_8dev():
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch import roofline as RL
-from repro.runtime import make_mesh, set_mesh
+from repro.runtime import make_mesh
 
 mesh = make_mesh((2, 4), ("data", "model"))
 L, D, F = 6, 64, 128
@@ -105,7 +105,7 @@ def f(params, x):
     x, _ = jax.lax.scan(body, x, params)
     return x.sum()
 
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     comp = jax.jit(f, in_shardings=(
         NamedSharding(mesh, P(None, None, "model")),
         NamedSharding(mesh, P("data", None)),

@@ -1,11 +1,7 @@
 """Partition rules: divisibility fallback, axis-reuse guard, rule sets."""
 import jax
 import pytest
-
-try:
-    from jax.sharding import AbstractMesh, AxisType
-except ImportError:  # older jax without AxisType
-    pytest.skip("jax.sharding.AxisType unavailable", allow_module_level=True)
+from jax.sharding import AbstractMesh, AxisType
 from jax.sharding import PartitionSpec as P
 
 from repro.models.spec import PSpec

@@ -12,7 +12,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.models.attention import decode_attention
 from repro.models.splitkv import split_kv_decode
-from repro.runtime import make_mesh, set_mesh
+from repro.runtime import make_mesh
 
 mesh = make_mesh((4, 2), ("data", "model"))
 B, S, H, KV, D = 2, 64, 4, 2, 16
@@ -27,7 +27,7 @@ ref = decode_attention(q, k, v, length)
 for axes in (("data",), ("data", "model")):
     k_sh = jax.device_put(k, NamedSharding(mesh, P(None, axes)))
     v_sh = jax.device_put(v, NamedSharding(mesh, P(None, axes)))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda q, k, v, l: split_kv_decode(
             q, k, v, l, mesh=mesh, seq_axes=axes))(q, k_sh, v_sh, length)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
