@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the chip: one
+minus the union of the device's op intervals over the window (%)."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    return None if t is None else 100.0 * (1.0 - t["busy_s"] / t["window_s"])
