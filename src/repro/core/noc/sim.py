@@ -44,6 +44,10 @@ from repro.core.noc.params import (
 )
 from repro.core.noc.topology import Topology
 
+# host spans of the drivers (run, run_sweep) on the profiler's host plane;
+# they record nothing unless a profile is being captured
+_span = jax.profiler.TraceAnnotation
+
 
 @jax.tree_util.register_dataclass
 @dataclass
@@ -499,31 +503,39 @@ class Sim:
         #    delivery is held (memory-server-style stall into the fabric)
         #    while that queue is full — previously the push silently
         #    overwrote the newest entry, corrupting a flit.
-        rsp_free = st.eps.eg_cnt[CH_RSP] < EQ
-        space = jnp.ones((C, E), bool).at[CH_REQ].set(rsp_free)
-        er, ep_p = self.tables.ep_attach[:, 0], self.tables.ep_attach[:, 1]
-        req_waiting = st.fabric.out_cnt[CH_REQ, er, ep_p] > 0
-        fabric, ep_flit, ep_valid = eng.fabric_cycle(
-            st.fabric, self.tables, space, backend=self.params.backend,
-            router_tile=self.params.router_tile, fused_fifo=fast)
+        # Each phase runs in a named scope (noc/README.md, "Profiling a
+        # run"): the names reach the compiled program's op metadata, so a
+        # device profile splits by layer. Scopes change metadata, not ops.
+        with jax.named_scope("noc.router"):
+            rsp_free = st.eps.eg_cnt[CH_RSP] < EQ
+            space = jnp.ones((C, E), bool).at[CH_REQ].set(rsp_free)
+            er, ep_p = self.tables.ep_attach[:, 0], self.tables.ep_attach[:, 1]
+            req_waiting = st.fabric.out_cnt[CH_REQ, er, ep_p] > 0
+            fabric, ep_flit, ep_valid = eng.fabric_cycle(
+                st.fabric, self.tables, space, backend=self.params.backend,
+                router_tile=self.params.router_tile, fused_fifo=fast)
         # 2) endpoint processing
-        eps = _ingest(st.eps, ep_flit, ep_valid, cycle, self.params, wl)
-        eps = dataclasses.replace(
-            eps, eg_overflow=eps.eg_overflow
-            + (req_waiting & ~rsp_free).astype(jnp.int32))
-        eps = _generators(eps, cycle, self.params, wl, wl.n_tiles)
-        eps = _memory(eps, cycle, self.params, self.is_hbm, self.is_mem)
+        with jax.named_scope("noc.ingest"):
+            eps = _ingest(st.eps, ep_flit, ep_valid, cycle, self.params, wl)
+            eps = dataclasses.replace(
+                eps, eg_overflow=eps.eg_overflow
+                + (req_waiting & ~rsp_free).astype(jnp.int32))
+        with jax.named_scope("noc.generators"):
+            eps = _generators(eps, cycle, self.params, wl, wl.n_tiles)
+        with jax.named_scope("noc.memory"):
+            eps = _memory(eps, cycle, self.params, self.is_hbm, self.is_mem)
         # 3) egress -> injection: every channel's head whose ready time came
-        head, ready_ts = epm._eg_peek(eps.eg, eps.eg_ready, eps.eg_head,
-                                      circular=fast)
-        ready = (eps.eg_cnt > 0) & (ready_ts <= cycle)  # [C, E]
-        fabric, accepted = eng.inject(fabric, self.tables, head, ready,
-                                      scatter=fast)
-        eg, eg_ready, eg_head, eg_cnt = epm._eg_pop(
-            eps.eg, eps.eg_ready, eps.eg_head, eps.eg_cnt, accepted,
-            circular=fast)
-        eps = dataclasses.replace(eps, eg=eg, eg_ready=eg_ready,
-                                  eg_head=eg_head, eg_cnt=eg_cnt)
+        with jax.named_scope("noc.inject"):
+            head, ready_ts = epm._eg_peek(eps.eg, eps.eg_ready, eps.eg_head,
+                                          circular=fast)
+            ready = (eps.eg_cnt > 0) & (ready_ts <= cycle)  # [C, E]
+            fabric, accepted = eng.inject(fabric, self.tables, head, ready,
+                                          scatter=fast)
+            eg, eg_ready, eg_head, eg_cnt = epm._eg_pop(
+                eps.eg, eps.eg_ready, eps.eg_head, eps.eg_cnt, accepted,
+                circular=fast)
+            eps = dataclasses.replace(eps, eg=eg, eg_ready=eg_ready,
+                                      eg_head=eg_head, eg_cnt=eg_cnt)
         return SimState(fabric=fabric, eps=eps, cycle=cycle + 1), (ep_flit, ep_valid)
 
     def step_super(self, st: SimState, wl: epm.Workload | None = None):
@@ -556,42 +568,47 @@ class Sim:
         E = self.topo.n_endpoints
         C = self.params.n_channels
         EQ = st.eps.eg_ready.shape[-1]
-        rsp_free = st.eps.eg_cnt[CH_RSP] < EQ
-        space = jnp.ones((C, E), bool).at[CH_REQ].set(rsp_free)
-        (fabric, eg, eg_ready, eg_head, eg_cnt, dF, dV, dW) = (
-            eng.fabric_cycles_fused(
-                st.fabric, self.tables, space, st.eps.eg, st.eps.eg_ready,
-                st.eps.eg_head, st.eps.eg_cnt, cycle, k,
-                backend=self.params.backend))
-        eps = dataclasses.replace(st.eps, eg=eg, eg_ready=eg_ready,
-                                  eg_head=eg_head, eg_cnt=eg_cnt)
-        # [C, k, ...] -> [k, C, ...] for the per-cycle endpoint replay
-        dF, dV, dW = (jnp.moveaxis(x, 1, 0) for x in (dF, dV, dW))
+        with jax.named_scope("noc.router"):  # scopes as in step
+            rsp_free = st.eps.eg_cnt[CH_RSP] < EQ
+            space = jnp.ones((C, E), bool).at[CH_REQ].set(rsp_free)
+            (fabric, eg, eg_ready, eg_head, eg_cnt, dF, dV, dW) = (
+                eng.fabric_cycles_fused(
+                    st.fabric, self.tables, space, st.eps.eg, st.eps.eg_ready,
+                    st.eps.eg_head, st.eps.eg_cnt, cycle, k,
+                    backend=self.params.backend))
+            eps = dataclasses.replace(st.eps, eg=eg, eg_ready=eg_ready,
+                                      eg_head=eg_head, eg_cnt=eg_cnt)
+            # [C, k, ...] -> [k, C, ...] for the per-cycle endpoint replay
+            dF, dV, dW = (jnp.moveaxis(x, 1, 0) for x in (dF, dV, dW))
 
         def ep_body(carry, xs):
             """Endpoint phases of one window cycle (ingest/gen/memory)."""
             eps, cyc = carry
             flits, valids, waiting = xs
-            eps = _ingest(eps, flits, valids, cyc, self.params, wl)
-            eps = dataclasses.replace(
-                eps, eg_overflow=eps.eg_overflow
-                + (waiting[CH_REQ] & ~rsp_free).astype(jnp.int32))
-            eps = _generators(eps, cyc, self.params, wl, wl.n_tiles)
-            eps = _memory(eps, cyc, self.params, self.is_hbm, self.is_mem)
+            with jax.named_scope("noc.ingest"):
+                eps = _ingest(eps, flits, valids, cyc, self.params, wl)
+                eps = dataclasses.replace(
+                    eps, eg_overflow=eps.eg_overflow
+                    + (waiting[CH_REQ] & ~rsp_free).astype(jnp.int32))
+            with jax.named_scope("noc.generators"):
+                eps = _generators(eps, cyc, self.params, wl, wl.n_tiles)
+            with jax.named_scope("noc.memory"):
+                eps = _memory(eps, cyc, self.params, self.is_hbm, self.is_mem)
             return (eps, cyc + 1), None
 
         (eps, _), _ = jax.lax.scan(ep_body, (eps, cycle), (dF, dV, dW))
 
-        head, ready_ts = epm._eg_peek(eps.eg, eps.eg_ready, eps.eg_head,
-                                      circular=True)
-        ready = (eps.eg_cnt > 0) & (ready_ts <= cycle + (k - 1))
-        fabric, accepted = eng.inject(fabric, self.tables, head, ready,
-                                      scatter=True)
-        eg, eg_ready, eg_head, eg_cnt = epm._eg_pop(
-            eps.eg, eps.eg_ready, eps.eg_head, eps.eg_cnt, accepted,
-            circular=True)
-        eps = dataclasses.replace(eps, eg=eg, eg_ready=eg_ready,
-                                  eg_head=eg_head, eg_cnt=eg_cnt)
+        with jax.named_scope("noc.inject"):
+            head, ready_ts = epm._eg_peek(eps.eg, eps.eg_ready, eps.eg_head,
+                                          circular=True)
+            ready = (eps.eg_cnt > 0) & (ready_ts <= cycle + (k - 1))
+            fabric, accepted = eng.inject(fabric, self.tables, head, ready,
+                                          scatter=True)
+            eg, eg_ready, eg_head, eg_cnt = epm._eg_pop(
+                eps.eg, eps.eg_ready, eps.eg_head, eps.eg_cnt, accepted,
+                circular=True)
+            eps = dataclasses.replace(eps, eg=eg, eg_ready=eg_ready,
+                                      eg_head=eg_head, eg_cnt=eg_cnt)
         return SimState(fabric=fabric, eps=eps, cycle=cycle + k), (dF, dV)
 
     def _scan_fn(self, n_cycles: int, with_trace: bool,
@@ -610,8 +627,9 @@ class Sim:
                     f"fused_cycles={k}")
 
             @jax.jit
-            def fn(st):
-                """Scan ``step`` for n_cycles (closure-jitted)."""
+            def scan(st):
+                """Scan ``step`` for n_cycles (closure-jitted; its program
+                is ``jit_scan`` in a profile)."""
                 def body(s, _):
                     """One scan step: advance a (super-)cycle, maybe trace."""
                     if k > 1:
@@ -624,7 +642,7 @@ class Sim:
 
                 return jax.lax.scan(body, st, None, length=n_cycles // max(k, 1))
 
-            self._jit_cache[key] = fn
+            fn = self._jit_cache[key] = scan
         return fn
 
     def _sweep_fn(self, n_cycles: int, fields: tuple):
@@ -637,8 +655,9 @@ class Sim:
         fn = self._jit_cache.get(key)
         if fn is None:
             @jax.jit
-            def fn(batch):
-                """Vmapped scan over the batched workload arrays."""
+            def sweep(batch):
+                """Vmapped scan over the batched workload arrays (its
+                program is ``jit_sweep`` in a profile)."""
                 def one(values):
                     """Scan one workload configuration to its final state."""
                     wl = dataclasses.replace(self.wl, **dict(zip(fields, values)))
@@ -651,7 +670,7 @@ class Sim:
                     return s
                 return jax.vmap(one)(batch)
 
-            self._jit_cache[key] = fn
+            fn = self._jit_cache[key] = sweep
         return fn
 
 
@@ -740,9 +759,12 @@ def run(sim: Sim, n_cycles: int, state: SimState | None = None) -> SimState:
     must be a multiple). The incoming ``state`` is consumed — do not reuse
     it after this call (re-init or use the returned state).
     """
-    st = state if state is not None else sim.init_state()
-    s, _ = sim._scan_fn(n_cycles, with_trace=False)(st)
-    _consume_state(st)
+    with _span("noc.run"):
+        st = state if state is not None else sim.init_state()
+        with _span("noc.run.scan"):
+            s, _ = sim._scan_fn(n_cycles, with_trace=False)(st)
+        with _span("noc.run.consume"):
+            _consume_state(st)
     return s
 
 
@@ -881,13 +903,19 @@ def run_sweep(sim: Sim, wls: list[epm.Workload], n_cycles: int) -> list[SimState
                     "fields are taken from the reference sim.wl, so a field "
                     "only some workloads set would be silently ignored)")
     fields = tuple(f for f in SWEEP_FIELDS if getattr(ref, f) is not None)
-    batch = tuple(
-        jnp.stack([jnp.asarray(getattr(w, f)) for w in wls]) for f in fields
-    )
-    final = sim._sweep_fn(n_cycles, fields)(batch)
-    for b in batch:
-        b.delete()
-    return [jax.tree.map(lambda x, i=i: x[i], final) for i in range(len(wls))]
+    with _span("noc.sweep"):
+        with _span("noc.sweep.stack"):
+            batch = tuple(
+                jnp.stack([jnp.asarray(getattr(w, f)) for w in wls])
+                for f in fields)
+        with _span("noc.sweep.scan"):
+            final = sim._sweep_fn(n_cycles, fields)(batch)
+        with _span("noc.sweep.delete"):
+            for b in batch:
+                b.delete()
+        with _span("noc.sweep.unstack"):
+            return [jax.tree.map(lambda x, i=i: x[i], final)
+                    for i in range(len(wls))]
 
 
 def stats(sim: Sim, st: SimState) -> dict:

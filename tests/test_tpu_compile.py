@@ -85,6 +85,16 @@ def test_jnp_scan_compiles_for_v5e(name, one_chip):
     assert used < V5E_HBM_BYTES, f"{name}: {used / 1e9:.2f} GB"
 
 
+def test_scan_compiled_for_v5e_keeps_the_layer_scopes(one_chip):
+    """The chip's compiler keeps the scan step's named scopes in the op
+    metadata of the 8x4 scan, where a profile's reduction reads them."""
+    spec, _ = SCANS["mesh8x4"]
+    text = _compile_scan(_sim(spec), 16, one_chip).as_text()
+    for scope in ("noc.router", "noc.ingest", "noc.generators", "noc.memory",
+                  "noc.inject"):
+        assert f"/{scope}/" in text, scope
+
+
 def _kernel_args(spec, n_channels=3, groups=None):
     topo, params = spec.lower()
     tb = E.make_tables(topo, params.n_vcs, groups=groups)
