@@ -648,9 +648,10 @@ class Sim:
     def _sweep_fn(self, n_cycles: int, fields: tuple):
         """One jitted vmapped scan over N workload configs at once: the
         workload arrays become traced inputs instead of baked-in constants,
-        so the whole sweep compiles exactly once. The batched workload
-        arrays are consumed (run_sweep stacks a fresh batch per call and
-        deletes it after the scan)."""
+        so the whole sweep compiles exactly once per batch size. The same
+        program splits the batched final state, so one call takes the
+        stacked fields in and returns a list of N SimStates, each in
+        buffers of its own."""
         key = ("sweep", n_cycles, fields)
         fn = self._jit_cache.get(key)
         if fn is None:
@@ -668,7 +669,10 @@ class Sim:
                     s, _ = jax.lax.scan(body, self.init_state(wl), None,
                                         length=n_cycles)
                     return s
-                return jax.vmap(one)(batch)
+                final = jax.vmap(one)(batch)
+                n = len(batch[0])
+                return [jax.tree.map(lambda x, i=i: x[i], final)
+                        for i in range(n)]
 
             fn = self._jit_cache[key] = sweep
         return fn
@@ -882,7 +886,9 @@ def run_sweep(sim: Sim, wls: list[epm.Workload], n_cycles: int) -> list[SimState
     shape); the array-valued fields are batched into traced inputs, so the
     scan body compiles exactly once for the whole sweep instead of once per
     configuration (each ``build_sim`` + ``run`` bakes its workload in as
-    constants and recompiles). Returns one final SimState per workload.
+    constants and recompiles). Returns one final SimState per workload,
+    split from the batch inside that same program: a call is one program
+    launch, with the fields stacked on the host beforehand.
     """
     ref = sim.wl
     for w in wls:
@@ -905,17 +911,10 @@ def run_sweep(sim: Sim, wls: list[epm.Workload], n_cycles: int) -> list[SimState
     fields = tuple(f for f in SWEEP_FIELDS if getattr(ref, f) is not None)
     with _span("noc.sweep"):
         with _span("noc.sweep.stack"):
-            batch = tuple(
-                jnp.stack([jnp.asarray(getattr(w, f)) for w in wls])
-                for f in fields)
+            batch = tuple(np.stack([np.asarray(getattr(w, f)) for w in wls])
+                          for f in fields)
         with _span("noc.sweep.scan"):
-            final = sim._sweep_fn(n_cycles, fields)(batch)
-        with _span("noc.sweep.delete"):
-            for b in batch:
-                b.delete()
-        with _span("noc.sweep.unstack"):
-            return [jax.tree.map(lambda x, i=i: x[i], final)
-                    for i in range(len(wls))]
+            return sim._sweep_fn(n_cycles, fields)(batch)
 
 
 def stats(sim: Sim, st: SimState) -> dict:

@@ -4,6 +4,7 @@ calibrated analytical model (repro.core.collectives.FabricCollectiveModel),
 a golden-stats pin, and the vmapped multi-config sweep engine."""
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 
@@ -216,6 +217,23 @@ def test_run_sweep_compiles_once():
     # same shape signature => cache hit, still one entry
     S.run_sweep(sim, list(reversed(wls)), 50)
     assert len([k for k in sim._jit_cache if k[0] == "sweep"]) == 1
+
+
+def test_run_sweep_states_own_their_buffers():
+    """Each returned state owns its buffers: continuing one fabric, which
+    deletes the consumed state's large buffers, leaves every other fabric
+    whole, even one with the same values (fabrics 0 and 2 here)."""
+    topo = build_mesh(nx=4, ny=2)
+    wls = [T.dma_workload(topo, p, transfer_kb=1, n_txns=2)
+           for p in ("uniform", "neighbor", "uniform")]
+    sim = S.build_sim(topo, NocParams(), wls[0])
+    finals = S.run_sweep(sim, wls, 200)
+    S.run(sim, 100, finals[0])
+    assert finals[0].fabric.in_buf.is_deleted()
+    fresh = S.run_sweep(sim, wls, 200)
+    for k in (1, 2):
+        for got, want in zip(jax.tree.leaves(finals[k]), jax.tree.leaves(fresh[k])):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_run_sweep_rejects_static_mismatch():

@@ -7,7 +7,6 @@ import glob
 import os
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -24,7 +23,7 @@ def _scan_text(sim, n_cycles):
 
 def _sweep_text(sim, wls, n_cycles):
     fields = tuple(f for f in S.SWEEP_FIELDS if getattr(wls[0], f) is not None)
-    batch = tuple(jnp.stack([jnp.asarray(getattr(w, f)) for w in wls]) for f in fields)
+    batch = tuple(np.stack([np.asarray(getattr(w, f)) for w in wls]) for f in fields)
     return sim._sweep_fn(n_cycles, fields).lower(batch).compile().as_text()
 
 
@@ -42,9 +41,9 @@ def test_compiled_program_carries_every_scope(program):
         assert f'/{scope}/' in text, f"{program}: no op carries {scope}"
 
 
-def _profiled(tmp_path, fn):
-    """``fn()``'s result and the host-plane ``noc.*`` spans of its profile,
-    as ``(name, start_ns, end_ns, line)``."""
+def _profiled(tmp_path, fn, prefix="noc."):
+    """``fn()``'s result and the host-plane events of its profile whose
+    names start with ``prefix``, as ``(name, start_ns, end_ns, line)``."""
     from jax.profiler import ProfileData
 
     jax.profiler.start_trace(str(tmp_path))
@@ -54,11 +53,11 @@ def _profiled(tmp_path, fn):
         jax.profiler.stop_trace()
     path, = glob.glob(os.path.join(tmp_path, "plugins", "profile", "*", "*.xplane.pb"))
     pd = ProfileData.from_file(path)
-    spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, line.name)
-             for plane in pd.planes if plane.name == "/host:CPU"
-             for line in plane.lines for ev in line.events
-             if ev.name.startswith("noc.")]
-    return out, spans
+    events = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, line.name)
+              for plane in pd.planes if plane.name == "/host:CPU"
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith(prefix)]
+    return out, events
 
 
 def _assert_nested(spans, outer, inner):
@@ -93,7 +92,21 @@ def test_run_spans_nest_and_keep_the_golden_state(tmp_path):
 def test_sweep_spans_nest_and_keep_the_golden_state(tmp_path):
     sim = _golden_sim()
     finals, spans = _profiled(tmp_path, lambda: S.run_sweep(sim, [sim.wl, sim.wl], 1200))
-    _assert_nested(spans, "noc.sweep", ("noc.sweep.stack", "noc.sweep.scan",
-                                        "noc.sweep.delete", "noc.sweep.unstack"))
+    _assert_nested(spans, "noc.sweep", ("noc.sweep.stack", "noc.sweep.scan"))
     for st in finals:
         _assert_golden(sim, st)
+
+
+def test_warm_sweep_call_launches_one_program(tmp_path):
+    """A warm ``run_sweep`` call launches ``jit_sweep`` alone: the stacked
+    batch goes in and one state per fabric comes out, with no eager
+    slicing, squeezing, broadcasting or stacking programs around it."""
+    sim = _golden_sim()
+    wls = [sim.wl, sim.wl, sim.wl]
+    jax.block_until_ready(S.run_sweep(sim, wls, 40))
+    finals, events = _profiled(tmp_path, lambda: S.run_sweep(sim, wls, 40), prefix="")
+    (_, s0, e0, _), = [ev for ev in events if ev[0] == "noc.sweep"]
+    inside = [n for n, s, e, _ in events if s0 <= s and e <= e0]
+    assert {n for n in inside if n.startswith("PjitFunction(")} == {"PjitFunction(sweep)"}
+    assert inside.count("PjRtCpuExecutable::Execute") == 1
+    assert len(finals) == len(wls)
