@@ -4,7 +4,9 @@ set up, measure, check against the reference, and print the result line.
 Everything that belongs to one configuration, traffic mix or metric lives
 in a file of its own (``bench/configs``, ``bench/traffic``,
 ``bench/metrics``) and is found through ``BENCHMARK.json``, so a cell or a
-metric is added by adding files.
+metric is added by adding files. A traffic kind that ``drivers.KINDS``
+lacks brings its driver as ``bench/kinds/<kind>.py``, which exposes
+``Driver``.
 """
 from __future__ import annotations
 
@@ -43,13 +45,31 @@ def metrics_for(bench: dict, cell: str, trace: bool) -> list[dict]:
     return [m for m in group if cell in m.get("workloads", [cell])]
 
 
-def reader(metric: str):
-    """The ``read(ctx)`` function of ``bench/metrics/<metric>.py``."""
-    path = BENCH / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}", path)
+def _module(path: Path, name: str):
+    """The Python file ``path``, loaded as a module called ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric: str):
+    """The ``read(ctx)`` function of ``bench/metrics/<metric>.py``."""
+    return _module(BENCH / "metrics" / f"{metric}.py", f"bench_metric_{metric}").read
+
+
+def driver_class(kind: str):
+    """The driver of a traffic kind: ``drivers.KINDS[kind]``, or else the
+    ``Driver`` of ``bench/kinds/<kind>.py``."""
+    from bench.lib import drivers
+
+    if kind in drivers.KINDS:
+        return drivers.KINDS[kind]
+    path = BENCH / "kinds" / f"{kind}.py"
+    if not path.is_file():
+        raise KeyError(f"no driver for traffic kind {kind!r}: not in drivers.KINDS "
+                       f"and no file {path.name} in {path.parent}")
+    return _module(path, f"bench_kind_{kind}").Driver
 
 
 def devices(chips: int, require_tpu: bool):
@@ -82,7 +102,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     from repro.compile_cache import enable_compile_cache
 
     enable_compile_cache()
-    driver = drivers.KINDS[traffic["kind"]](config, traffic, seed)
+    driver = driver_class(traffic["kind"])(config, traffic, seed)
     driver.phase["start"] = time.perf_counter() - t_start
     driver.setup()
     setup_s = time.perf_counter() - t_start
@@ -91,7 +111,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     traced_calls = traffic.get("trace_calls", 1)
     tracer = drivers.Tracer(log_dir, traced_calls)
     try:
-        window = driver.window(seconds, tracer)
+        with driver.phase("window"):
+            window = driver.window(seconds, tracer)
         info = device_info(devs)
         with driver.phase("trace_reduce"):
             reduced = tr.reduce_dir(log_dir) if trace else None
@@ -112,6 +133,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         if v is not None:
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     if reduced:
+        for plane, d in reduced["devices"].items():
+            print(f"bench: {plane} busy {d['busy_s']:.6f} s, {d['n_ops']} op events "
+                  f"in a {reduced['window_s']:.6f} s window", file=sys.stderr)
         info["busy_s"] = reduced["busy_s"]
         info["window_s"] = reduced["window_s"]
     out = {"correct": all(v <= lim for v, lim in checks.values()),

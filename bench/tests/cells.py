@@ -18,6 +18,14 @@ SMALL = {
     "floonoc8x4.fig8_sweep": ({"fabric": {"topology": "mesh", "nx": 4, "ny": 2}},
                               {"patterns": ["uniform", "neighbor", "tiled-matmul"],
                                "burst_kb": [1, 4], "cycles_per_call": 640}),
+    # the shape of dse.default_grid(smoke=True): 2 fabrics x 2 patterns x 1
+    # size; one worker process, since the CPU backend would otherwise fan
+    # the groups out over a pool that cannot return states
+    "dse_grid.default": ({"grid": [
+        {"fabric": {"topology": "mesh", "nx": 4, "ny": 4}, "patterns": ["uniform", "neighbor"]},
+        {"fabric": {"topology": "torus", "nx": 4, "ny": 4, "n_vcs": 2},
+         "patterns": ["uniform", "neighbor"]}],
+        "sizes": [[1, 2]], "run_dse": {"workers": 1, "n_cycles": None}}, {}),
 }
 
 
@@ -63,15 +71,23 @@ def control(kind: str):
 
 
 @contextlib.contextmanager
-def fault(kind: str):
+def fault(kind: str, point: int = 0):
     """Break the timed path underneath the harness.
 
     ``unchanged``: every simulation call returns its states as they came
     (a chained chunk its input, a sweep its fabrics' initial states).
     ``half``: a sweep leaves every other fabric of its batch at its
     initial state. ``altered``: one endpoint's received-beat count is off
-    by one in every returned state.
+    by one in every returned state. A design-space pass (``run_dse``)
+    runs its groups through the sweep and so takes those three; besides,
+    ``point``: the state it returns for point ``point`` is altered after
+    scoring; ``area``: that point's row reads a larger area;
+    ``one_device``: every group runs on the first device.
     """
+    if kind in ("point", "area", "one_device"):
+        with _dse_fault(kind, point):
+            yield
+        return
     from repro.core.noc import sim as S
 
     run, sweep = S.run, S.run_sweep
@@ -99,6 +115,37 @@ def fault(kind: str):
         yield
     finally:
         S.run, S.run_sweep = run, sweep
+
+
+@contextlib.contextmanager
+def _dse_fault(kind: str, point: int):
+    import jax
+
+    from repro.core.noc import dse
+
+    run_dse, devices = dse.run_dse, jax.devices
+
+    def bad_run_dse(specs, **kw):
+        if kind == "one_device":
+            jax.devices = lambda *a: devices(*a)[:1]
+            try:
+                return run_dse(specs, **kw)
+            finally:
+                jax.devices = devices
+        rows = run_dse(specs, **kw)
+        if kind == "area":
+            rows[point]["area_mm2"] += 1e-3
+        else:
+            st = rows[point]["state"]
+            eps = dataclasses.replace(st.eps, beats_rcvd=st.eps.beats_rcvd.at[0].add(1))
+            rows[point]["state"] = dataclasses.replace(st, eps=eps)
+        return rows
+
+    dse.run_dse = bad_run_dse
+    try:
+        yield
+    finally:
+        dse.run_dse = run_dse
 
 
 def with_control(loaded: tuple, kind: str) -> tuple:

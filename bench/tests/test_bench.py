@@ -63,7 +63,8 @@ def test_cell_is_correct(name):
 
 
 FAULTS = [("floonoc8x4.perm4_dma", f) for f in ("unchanged", "altered")] + [
-    ("floonoc8x4.fig8_sweep", f) for f in ("unchanged", "half", "altered")]
+    ("floonoc8x4.fig8_sweep", f) for f in ("unchanged", "half", "altered")] + [
+    ("dse_grid.default", f) for f in ("unchanged", "half", "altered", "area")]
 
 
 @pytest.mark.parametrize("name,fault", FAULTS)
@@ -75,7 +76,7 @@ def test_fault_is_caught(name, fault):
 
 
 CONTROLS = [("floonoc8x4.perm4_dma", "fused8"), ("floonoc8x4.perm4_dma", "wormhole"),
-            ("floonoc8x4.fig8_sweep", "wormhole")]
+            ("floonoc8x4.fig8_sweep", "wormhole"), ("dse_grid.default", "wormhole")]
 
 
 @pytest.mark.parametrize("name,kind", CONTROLS)
@@ -102,10 +103,11 @@ def test_union_and_gaps():
     assert trace._host_activity(spans, 11) == "host"
 
 
-def test_traced_run_is_correct():
+@pytest.mark.parametrize("name", ["floonoc8x4.perm4_dma", "dse_grid.default"])
+def test_traced_run_is_correct(name):
     """A traced run checks the same; off the chip no device op is found,
     so the per-layer metrics are left out rather than read as 0."""
-    loaded = cells.small("floonoc8x4.perm4_dma")
+    loaded = cells.small(name)
     out = cells.harness.run_cell(loaded[1]["name"], SEED, 1.0, True, t_start=0.0,
                                  loaded=loaded, require_tpu=False)
     assert out["correct"], cells.dumps(out)
