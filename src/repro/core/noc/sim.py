@@ -249,13 +249,15 @@ def _generators(st: epm.EndpointState, cycle, params: NocParams, wl, n_tiles):
         # scheduled multi-phase DMA (collective lowering): destination,
         # beats and receive-gate are looked up per issue index; a transfer
         # only becomes eligible once the stream has received its gate count
-        # of complete write bursts (ring-step data dependency)
-        k = jnp.clip(st.d_seq, 0, wl.dma_dst_seq.shape[-1] - 1)[:, :, None]
-        at_k = lambda a: jnp.take_along_axis(jnp.asarray(a), k, axis=2)[..., 0]
-        dst_es = at_k(wl.dma_dst_seq).astype(jnp.int32)
-        beats = at_k(wl.dma_beats_seq)
-        gate_ok = st.rx_bursts >= at_k(wl.dma_gate)
-        enabled = dst_es != -1
+        # of complete write bursts (ring-step data dependency). Its own
+        # scope, traced only with a schedule (noc/README.md).
+        with jax.named_scope("noc.sched"):
+            k = jnp.clip(st.d_seq, 0, wl.dma_dst_seq.shape[-1] - 1)[:, :, None]
+            at_k = lambda a: jnp.take_along_axis(jnp.asarray(a), k, axis=2)[..., 0]
+            dst_es = at_k(wl.dma_dst_seq).astype(jnp.int32)
+            beats = at_k(wl.dma_beats_seq)
+            gate_ok = st.rx_bursts >= at_k(wl.dma_gate)
+            enabled = dst_es != -1
     else:
         # per-(e, s) desired destination for the *next* transfer
         odd = (st.d_seq % 2) == 1

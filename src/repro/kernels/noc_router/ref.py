@@ -163,10 +163,11 @@ def request_ports(h, in_cnt, route, vc_out=None, n_vcs: int = 1):
     req_port = jnp.take_along_axis(route, jnp.clip(h[..., F_DST], 0, E - 1),
                                    axis=1)
     if n_vcs > 1:
-        Pp = P // n_vcs
-        vout = jnp.take_along_axis(
-            vc_out, jnp.clip(req_port, 0, Pp - 1)[..., None], axis=-1)[..., 0]
-        req_port = req_port * n_vcs + vout
+        with jax.named_scope("noc.vc"):
+            Pp = P // n_vcs
+            vout = jnp.take_along_axis(
+                vc_out, jnp.clip(req_port, 0, Pp - 1)[..., None], axis=-1)[..., 0]
+            req_port = req_port * n_vcs + vout
     return jnp.where(in_cnt > 0, req_port, -1)  # [R, P_in]
 
 
@@ -433,21 +434,22 @@ def link_inputs(out_heads_all, out_valid_all, link_src, in_space,
         up_head = out_heads_all[sr, sp]
         up_valid = out_valid_all[sr, sp] & have_up
         return up_head, up_valid & in_space
-    V = n_vcs
-    R_all, PV = out_valid_all.shape
-    Pp = link_src.shape[-2]
-    src_r, src_p = link_src[..., 0], link_src[..., 1]  # [R, Pp]
-    have_up = src_r >= 0
-    sr = jnp.clip(src_r, 0, R_all - 1)[..., None]  # [R, Pp, 1]
-    slot = jnp.clip(src_p, 0, Pp - 1)[..., None] * V + jnp.arange(V)
-    up_heads = out_heads_all[sr, slot]  # [R, Pp, V, NF]
-    up_valid = out_valid_all[sr, slot] & have_up[..., None]  # [R, Pp, V]
-    space = in_space.reshape(*in_space.shape[:-1], Pp, V)
-    elig = up_valid & space
-    chosen_v = jnp.argmax(elig, axis=-1)  # first eligible VC (lowest wins)
-    accept = elig & (jnp.arange(V) == chosen_v[..., None])
-    up_head = up_heads.reshape(*in_space.shape, NF)
-    return up_head, accept.reshape(in_space.shape)
+    with jax.named_scope("noc.vc"):
+        V = n_vcs
+        R_all, PV = out_valid_all.shape
+        Pp = link_src.shape[-2]
+        src_r, src_p = link_src[..., 0], link_src[..., 1]  # [R, Pp]
+        have_up = src_r >= 0
+        sr = jnp.clip(src_r, 0, R_all - 1)[..., None]  # [R, Pp, 1]
+        slot = jnp.clip(src_p, 0, Pp - 1)[..., None] * V + jnp.arange(V)
+        up_heads = out_heads_all[sr, slot]  # [R, Pp, V, NF]
+        up_valid = out_valid_all[sr, slot] & have_up[..., None]  # [R, Pp, V]
+        space = in_space.reshape(*in_space.shape[:-1], Pp, V)
+        elig = up_valid & space
+        chosen_v = jnp.argmax(elig, axis=-1)  # first eligible VC (lowest wins)
+        accept = elig & (jnp.arange(V) == chosen_v[..., None])
+        up_head = up_heads.reshape(*in_space.shape, NF)
+        return up_head, accept.reshape(in_space.shape)
 
 
 def sent_mask(out_valid, link_dst, port_ep, in_space_all, ep_space,
@@ -478,17 +480,18 @@ def sent_mask(out_valid, link_dst, port_ep, in_space_all, ep_space,
                                   jnp.clip(dst_p, 0, P - 1)]
         sent_link = to_router & out_valid & down_space
     else:
-        V = n_vcs
-        R_all, PV = in_space_all.shape
-        Pp = link_dst.shape[-2]
-        dr = jnp.clip(dst_r, 0, R_all - 1)[..., None]  # [R, Pp, 1]
-        slot = jnp.clip(dst_p, 0, Pp - 1)[..., None] * V + jnp.arange(V)
-        down_space = in_space_all[dr, slot]  # [R, Pp, V]
-        ov = out_valid.reshape(*out_valid.shape[:-1], Pp, V)
-        elig = ov & down_space & to_router[..., None]
-        chosen_v = jnp.argmax(elig, axis=-1)
-        sent_link = (elig & (jnp.arange(V) == chosen_v[..., None])
-                     ).reshape(out_valid.shape)
+        with jax.named_scope("noc.vc"):
+            V = n_vcs
+            R_all, PV = in_space_all.shape
+            Pp = link_dst.shape[-2]
+            dr = jnp.clip(dst_r, 0, R_all - 1)[..., None]  # [R, Pp, 1]
+            slot = jnp.clip(dst_p, 0, Pp - 1)[..., None] * V + jnp.arange(V)
+            down_space = in_space_all[dr, slot]  # [R, Pp, V]
+            ov = out_valid.reshape(*out_valid.shape[:-1], Pp, V)
+            elig = ov & down_space & to_router[..., None]
+            chosen_v = jnp.argmax(elig, axis=-1)
+            sent_link = (elig & (jnp.arange(V) == chosen_v[..., None])
+                         ).reshape(out_valid.shape)
     has_ep = port_ep >= 0
     ep_ok = ep_space[jnp.clip(port_ep, 0, E - 1)]
     sent_ep = has_ep & out_valid & ep_ok

@@ -16,6 +16,7 @@ Pins the three contracts the VC datapath must honor:
   at ``n_vcs=2`` (``ml_traffic.required_vcs``).
 """
 import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -171,6 +172,66 @@ def test_auto_algo_follows_n_vcs_on_torus():
     mesh = CT.all_to_all(build_mesh(nx=4, ny=4), data_kb=8)
     assert mesh.meta["algo"] == "direct"
     assert "vc_chain" not in mesh.meta
+
+
+# ----------------------------------------------------------------------
+# seeded rank orders: the MoE dispatch with ranks placed at random
+# ----------------------------------------------------------------------
+# orders (np.random.default_rng(seed).permutation) that the 16 kB direct
+# rotation with two streams wedges on the VC-less torus
+A2A_ORDERS = [2, 3, 9]
+
+
+def _seeded_all_to_all(seed, n_vcs):
+    topo = build_torus(nx=4, ny=4)
+    order = np.random.default_rng(seed).permutation(16).astype(np.int32)
+    sched = CT.all_to_all(topo, data_kb=16, streams=2, order=order,
+                          algo="direct", n_vcs=n_vcs)
+    return topo, sched
+
+
+@pytest.mark.parametrize("seed", A2A_ORDERS)
+def test_seeded_direct_all_to_all_completes_exactly_once(seed):
+    """Any placement of the ranks on the 2-VC torus delivers every chunk
+    exactly once and retires every scheduled write."""
+    topo, sched = _seeded_all_to_all(seed, 2)
+    st, out = _run_sched(topo, sched, 1000, NocParams(n_vcs=2))
+    np.testing.assert_array_equal(out["rx_bursts"], sched.expect_rx)
+    assert int(np.asarray(st.eps.d_txns_left).sum()) == 0
+    assert int(np.asarray(st.eps.d_outst).sum()) == 0
+
+
+@pytest.mark.parametrize("seed", A2A_ORDERS)
+def test_seeded_direct_all_to_all_wedges_without_vcs(seed):
+    """The same schedule on the VC-less torus never completes: no receipt
+    lands in the second half of a run twice as long as the 2-VC fabric
+    needs."""
+    topo, sched = _seeded_all_to_all(seed, 1)
+    sim = S.build_sim(topo, NocParams(), CT.to_workload(topo, sched))
+    st = S.run(sim, 1000)
+    mid = np.asarray(st.eps.rx_bursts).copy()
+    st = S.run(sim, 1000, st)
+    rx = np.asarray(st.eps.rx_bursts)
+    assert (rx != sched.expect_rx).any()
+    np.testing.assert_array_equal(rx, mid)
+
+
+def test_vc_and_sched_scopes_only_where_traced():
+    """``noc.vc`` and ``noc.sched`` name ops of the 2-VC torus program
+    with a schedule, and of neither 8x4 mesh program (``jit_scan``,
+    ``jit_sweep``), where their branches are not traced."""
+    from test_noc_scopes import _scan_text, _sweep_text
+
+    topo, sched = _seeded_all_to_all(A2A_ORDERS[0], 2)
+    sim = S.build_sim(topo, NocParams(n_vcs=2), CT.to_workload(topo, sched))
+    text = _scan_text(sim, 8)
+    for scope in ("noc.vc", "noc.sched"):
+        assert re.search(rf'op_name="[^"]*[/(]{re.escape(scope)}[)/]', text), scope
+    mesh = build_mesh(nx=4, ny=8, hbm_west=True)
+    wl = T.dma_workload(mesh, "uniform", transfer_kb=1, n_txns=2, streams=4)
+    sim8 = S.build_sim(mesh, NocParams(), wl)
+    for text in (_scan_text(sim8, 8), _sweep_text(sim8, [wl, wl], 8)):
+        assert "noc.vc" not in text and "noc.sched" not in text
 
 
 # ----------------------------------------------------------------------
