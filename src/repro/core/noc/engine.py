@@ -114,15 +114,17 @@ class FabricTables:
 
     With ``n_vcs > 1``, ``port_ep``/``ep_attach`` are *slot*-level (slot =
     physical port * n_vcs + vc; endpoints always attach at VC0 of their
-    port) while ``route``/``link_src``/``link_dst`` stay physical —
-    arbitration expands a physical out-port to an output slot via
-    ``vc_out``, and the link stage folds V upstream slots back onto the
-    one physical wire. ``n_vcs=1`` keeps ``vc_out=None`` and every table
-    bit-identical to the historical fabric."""
+    port), and so are ``link_src``/``link_dst`` (a flit keeps its VC
+    across the wire: slot (p, v) links to the far slot (p2, v)), while
+    ``route`` stays physical — arbitration expands a physical out-port to
+    an output slot via ``vc_out``, and the link stage lets the V slots of
+    one physical wire move one flit a cycle between them. ``n_vcs=1``
+    keeps ``vc_out=None`` and every table bit-identical to the historical
+    fabric."""
 
     route: jnp.ndarray  # [R, E] physical out port
-    link_src: jnp.ndarray  # [R, Pp, 2] upstream (router, port) feeding my in port
-    link_dst: jnp.ndarray  # [R, Pp, 2]
+    link_src: jnp.ndarray  # [R, P, 2] upstream (router, port) feeding my in port
+    link_dst: jnp.ndarray  # [R, P, 2]; both slot-level if V > 1
     port_ep: jnp.ndarray  # [R, P] endpoint attached (-1); slot-level if V > 1
     ep_attach: jnp.ndarray  # [E, 2] (router, port-or-slot)
     # output VC for (router, input slot, physical out port); None when V == 1
@@ -241,6 +243,15 @@ def make_tables(topo: Topology, n_vcs: int = 1, groups=None) -> FabricTables:
             **offload,
         )
     V = n_vcs
+    # slot-level wiring: a flit keeps its VC across the wire, so slot
+    # (p, v) links to slot (p2, v) of the far end; no link stays -1
+    vc = np.tile(np.arange(V, dtype=np.int32), P)
+
+    def slot_links(link):
+        out = np.repeat(link, V, axis=1)
+        out[..., 1] = np.where(out[..., 0] >= 0, out[..., 1] * V + vc, -1)
+        return out
+
     # slot-level endpoint tables: endpoints live on VC0 of their port
     port_ep = np.full((R, P * V), -1, np.int32)
     port_ep[:, ::V] = topo.port_ep
@@ -263,8 +274,8 @@ def make_tables(topo: Topology, n_vcs: int = 1, groups=None) -> FabricTables:
                 vc_out[:, s, :] = vout
     return FabricTables(
         route=jnp.asarray(topo.route),
-        link_src=jnp.asarray(link_src),
-        link_dst=jnp.asarray(topo.link_to),
+        link_src=jnp.asarray(slot_links(link_src)),
+        link_dst=jnp.asarray(slot_links(topo.link_to)),
         port_ep=jnp.asarray(port_ep),
         ep_attach=jnp.asarray(ep_attach),
         vc_out=jnp.asarray(vc_out),

@@ -139,13 +139,13 @@ def router_cycle_pallas(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
 
     State is channel-batched (``in_buf`` [C, R, P, Din, NF], counters
     [C, R, P]); tables are shared across channels (``route`` [R, E],
-    ``link_src``/``link_dst`` [R, Pp, 2], ``port_ep`` [R, P], ``ep_attach``
+    ``link_src``/``link_dst`` [R, P, 2], ``port_ep`` [R, P], ``ep_attach``
     [E, 2]); ``ep_space`` [C, E] is the per-channel endpoint ingress-space
     mask. ``router_tile`` blocks K routers per program (grid
     ``(C, R / K)``, see ``effective_tile``); ``fused_fifo`` selects the
     fused FIFO datapath (must match the jnp side being compared against).
     With ``n_vcs > 1`` the state P axis is slot-level (physical ports
-    Pp = P / n_vcs; link tables stay physical) and the request lookup
+    Pp = P / n_vcs; route stays physical) and the request lookup
     expands through ``vc_out`` [R, P, Pp]; the kernels are the same.
     Returns the updated state plus the endpoint deliveries
     ``(ep_flit [C, E, NF], ep_valid [C, E])`` — identical, bit for bit, to
@@ -401,8 +401,8 @@ def router_cycles_fused_pallas(in_buf, in_cnt, out_buf, out_cnt, rr_ptr,
         in_specs=state_specs + [
             full_spec(R, E),  # route
             *vc_specs,  # vc_out (V > 1 only)
-            full_spec(R, Pp, 2),  # link_src (physical ports)
-            full_spec(R, Pp, 2),  # link_dst
+            full_spec(R, P, 2),  # link_src (slot-level)
+            full_spec(R, P, 2),  # link_dst
             full_spec(R, P),  # port_ep (slot-level)
             full_spec(E, 2),  # ep_attach
             chan_spec(E),  # ep_space

@@ -35,6 +35,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # packed flit layout: trailing axis of NF int32 fields
 FLIT_FIELDS = ("dst", "src", "kind", "txn", "last", "ts", "meta")
@@ -406,6 +407,33 @@ def offload_decisions(req_port, h, in_cnt, out_cnt, rr_ptr, wh_lock,
             red_acc2, red_got2)
 
 
+def _lowest_vc(elig, n_vcs: int):
+    """Of each physical port's ``n_vcs`` slots in ``elig`` [..., Pp * n_vcs],
+    keep only the lowest eligible one: slot p*V+v survives iff no slot
+    p*V+v' with v' < v is eligible. Elementwise over shifted slices,
+    unrolled over the static V: no argmax, no reduce, no trailing VC axis.
+    """
+    vc = np.arange(elig.shape[-1]) % n_vcs
+    pad = [(0, 0)] * (elig.ndim - 1)
+    blocked = jnp.zeros_like(elig)
+    for k in range(1, n_vcs):
+        lower = jnp.pad(elig[..., :-k], [*pad, (k, 0)])  # slot i - k
+        blocked |= lower & (vc >= k)
+    return elig & ~blocked
+
+
+def _upstream(out_heads_all, out_valid_all, link_src):
+    """The head [R, P, NF] and valid bit [R, P] of the output port (or
+    slot) that ``link_src`` [R, P, 2] wires to each input; no link reads
+    invalid."""
+    R_all, P = out_valid_all.shape
+    src_r, src_p = link_src[..., 0], link_src[..., 1]
+    have_up = src_r >= 0
+    sr = jnp.clip(src_r, 0, R_all - 1)
+    sp = jnp.clip(src_p, 0, P - 1)
+    return out_heads_all[sr, sp], out_valid_all[sr, sp] & have_up
+
+
 def link_inputs(out_heads_all, out_valid_all, link_src, in_space,
                 n_vcs: int = 1):
     """Link-traversal decisions for this router's *input* side.
@@ -423,37 +451,20 @@ def link_inputs(out_heads_all, out_valid_all, link_src, in_space,
     VC switching happens only at arbitration — so slot (p, v) can only
     receive from upstream output slot (src_p, v). Fixed-priority among
     eligible candidates always moves some flit, so sharing cannot deadlock
-    the wire.
+    the wire. ``link_src`` is slot-level (``engine.make_tables``), so the
+    gathers read straight into the slot axis with no trailing VC axis, and
+    the lowest-VC choice is elementwise over V (``_lowest_vc``).
     """
     if n_vcs == 1:
-        R_all, P = out_valid_all.shape
-        src_r, src_p = link_src[..., 0], link_src[..., 1]
-        have_up = src_r >= 0
-        sr = jnp.clip(src_r, 0, R_all - 1)
-        sp = jnp.clip(src_p, 0, P - 1)
-        up_head = out_heads_all[sr, sp]
-        up_valid = out_valid_all[sr, sp] & have_up
+        up_head, up_valid = _upstream(out_heads_all, out_valid_all, link_src)
         return up_head, up_valid & in_space
     with jax.named_scope("noc.vc"):
-        V = n_vcs
-        R_all, PV = out_valid_all.shape
-        Pp = link_src.shape[-2]
-        src_r, src_p = link_src[..., 0], link_src[..., 1]  # [R, Pp]
-        have_up = src_r >= 0
-        sr = jnp.clip(src_r, 0, R_all - 1)[..., None]  # [R, Pp, 1]
-        slot = jnp.clip(src_p, 0, Pp - 1)[..., None] * V + jnp.arange(V)
-        up_heads = out_heads_all[sr, slot]  # [R, Pp, V, NF]
-        up_valid = out_valid_all[sr, slot] & have_up[..., None]  # [R, Pp, V]
-        space = in_space.reshape(*in_space.shape[:-1], Pp, V)
-        elig = up_valid & space
-        chosen_v = jnp.argmax(elig, axis=-1)  # first eligible VC (lowest wins)
-        accept = elig & (jnp.arange(V) == chosen_v[..., None])
-        up_head = up_heads.reshape(*in_space.shape, NF)
-        return up_head, accept.reshape(in_space.shape)
+        up_head, up_valid = _upstream(out_heads_all, out_valid_all, link_src)
+        return up_head, _lowest_vc(up_valid & in_space, n_vcs)
 
 
 def sent_mask(out_valid, link_dst, port_ep, in_space_all, ep_space,
-              n_vcs: int = 1):
+              n_vcs: int = 1, link_accept_all=None):
     """Which of this router's output heads leave the buffer this cycle.
 
     A head is sent either over a live link — iff the downstream input FIFO
@@ -465,10 +476,12 @@ def sent_mask(out_valid, link_dst, port_ep, in_space_all, ep_space,
     ``out_valid[r, p] & in_space_all[dst_r, dst_p]`` because this port *is*
     the upstream of that input.
 
-    With ``n_vcs > 1`` the link leg recomputes ``link_inputs``'s
-    lowest-eligible-VC-first choice from the upstream side — same snapshot,
-    same winner — so exactly the accepted slot's head is popped. Endpoint
-    slots are VC0-only (slot-level ``port_ep``), so the ep leg is
+    With ``n_vcs > 1`` the link leg reads that decision back instead of
+    re-deriving it: a slot is sent iff the downstream ``link_accept_all``
+    [R_all, P] (``link_inputs``'s lowest-eligible-VC choice, same cycle)
+    took it at the paired slot. The wiring is one-to-one (``link_src`` is
+    ``link_dst``'s inverse), so exactly the accepted slot's head is popped.
+    Endpoint slots are VC0-only (slot-level ``port_ep``), so the ep leg is
     unchanged.
     """
     E = ep_space.shape[0]
@@ -481,17 +494,9 @@ def sent_mask(out_valid, link_dst, port_ep, in_space_all, ep_space,
         sent_link = to_router & out_valid & down_space
     else:
         with jax.named_scope("noc.vc"):
-            V = n_vcs
-            R_all, PV = in_space_all.shape
-            Pp = link_dst.shape[-2]
-            dr = jnp.clip(dst_r, 0, R_all - 1)[..., None]  # [R, Pp, 1]
-            slot = jnp.clip(dst_p, 0, Pp - 1)[..., None] * V + jnp.arange(V)
-            down_space = in_space_all[dr, slot]  # [R, Pp, V]
-            ov = out_valid.reshape(*out_valid.shape[:-1], Pp, V)
-            elig = ov & down_space & to_router[..., None]
-            chosen_v = jnp.argmax(elig, axis=-1)
-            sent_link = (elig & (jnp.arange(V) == chosen_v[..., None])
-                         ).reshape(out_valid.shape)
+            R_all, P = link_accept_all.shape
+            sent_link = to_router & link_accept_all[
+                jnp.clip(dst_r, 0, R_all - 1), jnp.clip(dst_p, 0, P - 1)]
     has_ep = port_ep >= 0
     ep_ok = ep_space[jnp.clip(port_ep, 0, E - 1)]
     sent_ep = has_ep & out_valid & ep_ok
@@ -509,14 +514,16 @@ def link_stage(out_buf, out_cnt, in_space, link_src, link_dst, port_ep,
     takes off its link, which output heads leave, and the endpoint
     deliveries. These are the gathers over the router axis that the link
     wiring implies; the Pallas backend runs this stage in XLA between its
-    arbitration and apply kernels.
+    arbitration and apply kernels. With ``n_vcs > 1`` the wire's VC is
+    chosen once, on the receiving side (elementwise over V), and ``sent``
+    reads that ``link_accept`` back through ``link_dst``.
     """
     out_heads = heads(out_buf)
     out_valid = out_cnt > 0
     up_head, link_accept = link_inputs(out_heads, out_valid, link_src,
                                        in_space, n_vcs=n_vcs)
     sent = sent_mask(out_valid, link_dst, port_ep, in_space, ep_space,
-                     n_vcs=n_vcs)
+                     n_vcs=n_vcs, link_accept_all=link_accept)
     er, ep_p = ep_attach[:, 0], ep_attach[:, 1]
     ep_flit = out_heads[er, ep_p]  # [E, NF]
     ep_valid = out_valid[er, ep_p] & ep_space

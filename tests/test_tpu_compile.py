@@ -9,6 +9,7 @@ what would not fit its 16 GB fails here. The topology is described inside
 a fixture, never at import: only one process may load the TPU library.
 """
 import dataclasses
+import re
 
 import jax
 import pytest
@@ -93,6 +94,18 @@ def test_scan_compiled_for_v5e_keeps_the_layer_scopes(one_chip):
     for scope in ("noc.router", "noc.ingest", "noc.generators", "noc.memory",
                   "noc.inject"):
         assert f"/{scope}/" in text, scope
+
+
+def test_torus_scan_compiled_for_v5e_picks_the_vc_without_a_reduce(one_chip):
+    """The 2-VC torus scan keeps its ``noc.vc`` scope on the chip, where
+    ``vc.device_us_per_fabric_cycle`` reads it, and the link stage's
+    lowest-VC choice compiles to no reduce: no instruction's ``op_name``
+    ends in ``noc.vc)/reduce``."""
+    spec, _ = SCANS["torus4x4_vc2"]
+    text = _compile_scan(_sim(spec), 16, one_chip).as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    assert any("noc.vc" in n for n in names)
+    assert [n for n in names if re.search(r"noc\.vc\)?/reduce$", n)] == []
 
 
 def _kernel_args(spec, n_channels=3, groups=None):
