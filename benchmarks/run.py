@@ -26,9 +26,6 @@ def main() -> None:
                     help="toy scale, fail on exceptions only")
     ap.add_argument("--json", default=None, help="write rows to this JSON file")
     ap.add_argument("--only", default=None, help="substring filter on module name")
-    ap.add_argument("--backend", default=None, choices=("jnp", "pallas"),
-                    help="router-cycle compute backend axis (modules that "
-                         "support it add per-backend rows)")
     args = ap.parse_args()
 
     from benchmarks import (
@@ -38,14 +35,12 @@ def main() -> None:
         fig9_area_power,
         fig10_rob,
         fig11_hbm,
-        sim_throughput,
         table1_links,
         table2_occamy,
         table3_soa,
     )
 
     modules = [
-        ("sim_throughput", sim_throughput),
         ("table1_links", table1_links),
         ("fig7_latency", fig7_latency),
         ("fig8_traffic", fig8_traffic),
@@ -74,13 +69,8 @@ def main() -> None:
         params = inspect.signature(mod.bench).parameters
         if args.smoke and "smoke" in params:
             kwargs["smoke"] = True
-        if args.backend and "backend" in params:
-            kwargs["backend"] = args.backend
-        # effective backend per row: modules without a backend kwarg always
-        # run jnp, whatever --backend asked for
-        row_backend = kwargs.get("backend") or "jnp"
         for r in mod.bench(**kwargs):
-            all_rows.append({"module": name, "backend": row_backend, **r})
+            all_rows.append({"module": name, **r})
             print(common.csv_line(r), flush=True)
             if r["ok"] is not None:
                 n_checked += 1
@@ -89,9 +79,7 @@ def main() -> None:
                     failed.append(r["name"])
     if args.json:
         with open(args.json, "w") as f:
-            # requested axis; each row carries its *effective* backend
-            json.dump({"smoke": args.smoke, "full": args.full,
-                       "backend": args.backend or "jnp", "device": dev,
+            json.dump({"smoke": args.smoke, "full": args.full, "device": dev,
                        "rows": all_rows}, f, indent=1, default=str,
                       sort_keys=True)
     print(f"\n# paper-validation: {n_ok}/{n_checked} targets matched", flush=True)

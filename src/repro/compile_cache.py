@@ -1,8 +1,7 @@
 """JAX's persistent compilation cache for the repo's entry points.
 
-Called by scripts (``chip_smoke.py``, ``benchmarks.run``,
-``benchmarks.sim_throughput``) before their first compile, never at
-library import.
+Called by scripts (``chip_smoke.py``, ``benchmarks.run``, ``bench/run.py``)
+before their first compile, never at library import.
 """
 from __future__ import annotations
 

@@ -10,10 +10,10 @@ The per-cycle router datapath itself — cycle-start snapshot, round-robin
 arbitration, wormhole-lock updates, FIFO push/pop — lives in
 ``repro.kernels.noc_router``:
 
-* ``ref.py`` is the reference implementation (the logic that used to be
-  inlined here as ``_cycle_one``); ``backend="jnp"`` vmaps it over the
-  leading channel axis, so the lax.scan step body contains no Python channel
-  loop and the traced op count is independent of the channel count.
+* ``ref.py`` is the reference implementation; ``backend="jnp"`` vmaps it
+  over the leading channel axis (``ops.router_cycle``), so the lax.scan
+  step body contains no Python channel loop and the traced op count is
+  independent of the channel count.
 * ``noc_router.py`` holds the Pallas kernels, gridded over (C, R / K) — one
   program per (channel, K-router block) — selected with
   ``backend="pallas"``: compiled on a TPU, interpreted elsewhere. Both
@@ -31,6 +31,7 @@ zero load, matching the paper's Fig. 7.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, replace
 
 import jax
@@ -56,8 +57,6 @@ from repro.kernels.noc_router.ref import (  # noqa: F401  (re-exported API)
     fifo_push,
     heads,
     pack_flit,
-    router_cycle_offload_reference,
-    router_cycle_reference,
 )
 
 
@@ -284,28 +283,6 @@ def make_tables(topo: Topology, n_vcs: int = 1, groups=None) -> FabricTables:
     )
 
 
-def _cycle_one(st: FabricState, tb: FabricTables, ep_ingress_space: jnp.ndarray):
-    """One cycle of a single channel (reference path; state [R, P, ...])."""
-    if tb.fork_out is not None:
-        (in2, in_cnt2, out2, out_cnt2, rr, wh, ep_flit, ep_valid,
-         racc2, rgot2) = router_cycle_offload_reference(
-            st.in_buf, st.in_cnt, st.out_buf, st.out_cnt, st.rr_ptr,
-            st.wh_lock, st.red_acc, st.red_got, tb.route, tb.link_src,
-            tb.link_dst, tb.port_ep, tb.ep_attach, tb.fork_out,
-            tb.red_parent, tb.red_need, ep_ingress_space,
-            n_endpoints=int(tb.ep_attach.shape[0]), vc_out=tb.vc_out,
-            n_vcs=tb.n_vcs)
-        return (FabricState(in2, in_cnt2, out2, out_cnt2, rr, wh,
-                            racc2, rgot2), ep_flit, ep_valid)
-    (in2, in_cnt2, out2, out_cnt2, rr, wh, ep_flit, ep_valid) = (
-        router_cycle_reference(
-            st.in_buf, st.in_cnt, st.out_buf, st.out_cnt, st.rr_ptr,
-            st.wh_lock, tb.route, tb.link_src, tb.link_dst, tb.port_ep,
-            tb.ep_attach, ep_ingress_space, vc_out=tb.vc_out,
-            n_vcs=tb.n_vcs))
-    return FabricState(in2, in_cnt2, out2, out_cnt2, rr, wh), ep_flit, ep_valid
-
-
 def _inject_one(st: FabricState, tb: FabricTables, flit: jnp.ndarray, want: jnp.ndarray):
     """Single-channel endpoint injection: flit [E, NF]; want [E]."""
     Din = st.in_buf.shape[-2]
@@ -323,7 +300,6 @@ def _inject_one(st: FabricState, tb: FabricTables, flit: jnp.ndarray, want: jnp.
 # channel axis of FabricState (tables are shared; ingress space is per-channel
 # so an endpoint can backpressure one channel — e.g. hold narrow requests
 # while its rsp egress queue is full — without stalling the others).
-_cycle_all = jax.vmap(_cycle_one, in_axes=(0, None, 0))
 _inject_all = jax.vmap(_inject_one, in_axes=(0, None, 0, 0))
 # gather-based injection (the fast path): each attach port pulls its
 # endpoint's flit (unique attach => expressible as a gather + one-hot
@@ -349,11 +325,8 @@ def fabric_cycle(st: FabricState, tb: FabricTables, ep_ingress_space: jnp.ndarra
     live contents; the naive reference path keeps it off). The backends
     are bit-identical for any fixed ``fused_fifo``. Returns (state',
     ep_flit [C, E, NF], ep_valid [C, E])."""
-    if backend == "jnp" and not fused_fifo:
-        return _cycle_all(st, tb, ep_ingress_space)
-    if tb.fork_out is not None:
-        (in2, in_cnt2, out2, out_cnt2, rr, wh, ep_flit, ep_valid,
-         racc2, rgot2) = router_ops.router_cycle(
+    (in2, in_cnt2, out2, out_cnt2, rr, wh, ep_flit, ep_valid, *red) = (
+        router_ops.router_cycle(
             st.in_buf, st.in_cnt, st.out_buf, st.out_cnt, st.rr_ptr,
             st.wh_lock, tb.route, tb.link_src, tb.link_dst, tb.port_ep,
             tb.ep_attach, ep_ingress_space, backend=backend,
@@ -361,45 +334,42 @@ def fabric_cycle(st: FabricState, tb: FabricTables, ep_ingress_space: jnp.ndarra
             fused_fifo=fused_fifo, vc_out=tb.vc_out, n_vcs=tb.n_vcs,
             fork_out=tb.fork_out, red_parent=tb.red_parent,
             red_need=tb.red_need, red_acc=st.red_acc, red_got=st.red_got,
-            n_endpoints=int(tb.ep_attach.shape[0]))
-        return (FabricState(in2, in_cnt2, out2, out_cnt2, rr, wh,
-                            racc2, rgot2), ep_flit, ep_valid)
-    (in2, in_cnt2, out2, out_cnt2, rr, wh, ep_flit, ep_valid) = (
-        router_ops.router_cycle(
-            st.in_buf, st.in_cnt, st.out_buf, st.out_cnt, st.rr_ptr,
-            st.wh_lock, tb.route, tb.link_src, tb.link_dst, tb.port_ep,
-            tb.ep_attach, ep_ingress_space, backend=backend,
-            interpret=interpret, router_tile=router_tile,
-            fused_fifo=fused_fifo, vc_out=tb.vc_out, n_vcs=tb.n_vcs))
-    return FabricState(in2, in_cnt2, out2, out_cnt2, rr, wh), ep_flit, ep_valid
+            n_endpoints=int(tb.ep_attach.shape[0])))
+    return (FabricState(in2, in_cnt2, out2, out_cnt2, rr, wh, *red),
+            ep_flit, ep_valid)
 
 
 def fabric_cycles_fused(st: FabricState, tb: FabricTables,
                         ep_ingress_space: jnp.ndarray,
                         eg, eg_ready, eg_head, eg_cnt, cycle0,
-                        n_cycles: int, backend: str = "jnp", interpret=None):
+                        n_cycles: int):
     """``n_cycles`` fused fabric cycles with egress injection threaded in.
 
     The multi-cycle super-step core: the fabric advances ``n_cycles`` with
     ``ep_ingress_space`` held and each endpoint's ready circular-egress
     head injected per cycle (except the window's last — the caller injects
     after the endpoint phases, making a 1-cycle window bit-identical to
-    ``fabric_cycle`` + ``inject``). On the Pallas backend the whole window
-    runs inside one kernel per channel with state resident across the
-    loop. Returns ``(state', eg, eg_ready, eg_head, eg_cnt,
-    ep_flit [C, N, E, NF], ep_valid [C, N, E], req_waiting [C, N, E])``.
-    Collective offload is per-cycle only (``fused_cycles == 1``).
+    ``fabric_cycle`` + ``inject``). The window is a ``lax.scan`` of
+    ``ref.fused_cycle_body`` vmapped over channels (jnp only). Returns
+    ``(state', eg, eg_ready, eg_head, eg_cnt, ep_flit [C, N, E, NF],
+    ep_valid [C, N, E], req_waiting [C, N, E])``. Collective offload is
+    per-cycle only (``fused_cycles == 1``).
     """
     if tb.fork_out is not None:
         raise ValueError(
             "collective offload does not support fused multi-cycle windows")
-    (in2, in_cnt2, out2, out_cnt2, rr, wh, eg, eg_ready, eg_head, eg_cnt,
-     ep_flit, ep_valid, waiting) = router_ops.router_cycles_fused(
-        st.in_buf, st.in_cnt, st.out_buf, st.out_cnt, st.rr_ptr, st.wh_lock,
-        eg, eg_ready, eg_head, eg_cnt,
-        tb.route, tb.link_src, tb.link_dst, tb.port_ep, tb.ep_attach,
-        ep_ingress_space, cycle0, n_cycles, backend=backend,
-        interpret=interpret, vc_out=tb.vc_out, n_vcs=tb.n_vcs)
+    # state + egress queues and ep_space are per-channel, tables and the
+    # cycle base are shared; the per-cycle outputs come out [C, N, ...]
+    scan_fn = jax.vmap(
+        functools.partial(router_ops_ref.router_cycles_scan,
+                          vc_out=tb.vc_out, n_vcs=tb.n_vcs),
+        in_axes=(0,) * 10 + (None,) * 5 + (0, None, None), out_axes=(0, 0))
+    (in2, in_cnt2, out2, out_cnt2, rr, wh, eg, eg_ready, eg_head, eg_cnt), (
+        ep_flit, ep_valid, waiting) = scan_fn(
+            st.in_buf, st.in_cnt, st.out_buf, st.out_cnt, st.rr_ptr,
+            st.wh_lock, eg, eg_ready, eg_head, eg_cnt,
+            tb.route, tb.link_src, tb.link_dst, tb.port_ep, tb.ep_attach,
+            ep_ingress_space, cycle0, n_cycles)
     return (FabricState(in2, in_cnt2, out2, out_cnt2, rr, wh),
             eg, eg_ready, eg_head, eg_cnt, ep_flit, ep_valid, waiting)
 

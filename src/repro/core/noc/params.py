@@ -62,8 +62,8 @@ class NocParams:
 
     # per-cycle router compute backend: "jnp" (vmapped reference) or
     # "pallas" ((C, R/K)-gridded kernels, compiled on a TPU and
-    # interpreted elsewhere; fused_cycles > 1 runs interpreted only and
-    # raises on a TPU). Bit-identical; see repro.kernels.noc_router and
+    # interpreted elsewhere; per-cycle only, so it requires
+    # fused_cycles == 1). Bit-identical; see repro.kernels.noc_router and
     # tests/test_noc_backend.py.
     backend: str = "jnp"
 
@@ -81,9 +81,10 @@ class NocParams:
     router_tile: int = 8
 
     # multi-cycle super-stepping: cycles the fabric advances per fused
-    # kernel call in sim.run(..., super_cycles=...) / Sim.step_super.
+    # window (a lax.scan of the jnp router cycle) in Sim.step_super.
     # 1 (default) is bit-identical to per-cycle stepping; >1 quantizes
-    # endpoint interaction to super-step boundaries (see core/noc/README).
+    # endpoint interaction to super-step boundaries (see core/noc/README)
+    # and runs on the jnp backend only.
     fused_cycles: int = 1
 
     # in-network collective offload (Colagrande et al. sequel paper):
@@ -94,7 +95,7 @@ class NocParams:
     # to the historical fabric — the offload tables/state are never
     # materialized and the pinned router traces carry no extra operands.
     # Requires fused_cycles == 1 (offload state is not threaded through the
-    # fused multi-cycle kernels); enforced at build_sim time.
+    # fused multi-cycle window).
     collective_offload: bool = False
 
     def __post_init__(self):
@@ -116,6 +117,10 @@ class NocParams:
         if self.collective_offload and self.fused_cycles != 1:
             raise ValueError(
                 "collective_offload requires fused_cycles == 1")
+        if self.backend == "pallas" and self.fused_cycles != 1:
+            raise ValueError(
+                "backend='pallas' requires fused_cycles == 1 (the fused "
+                "window runs on the jnp backend only)")
 
 
 # flit kinds

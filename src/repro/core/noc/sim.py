@@ -544,8 +544,8 @@ class Sim:
         """One super-step: ``params.fused_cycles`` cycles per fabric call.
 
         The fabric advances k cycles through ``eng.fabric_cycles_fused``
-        (one fused kernel launch per channel on the Pallas backend, state
-        resident across the window), recording per-cycle deliveries; the
+        (a ``lax.scan`` of the jnp router cycle; ``backend="pallas"`` is
+        refused at k>1 by ``NocParams``), recording per-cycle deliveries; the
         endpoint phases then replay those k cycles in order against their
         true cycle numbers, and the final egress injection closes the
         window. Requires ``step_impl="fast"`` (circular egress queues are
@@ -576,8 +576,7 @@ class Sim:
             (fabric, eg, eg_ready, eg_head, eg_cnt, dF, dV, dW) = (
                 eng.fabric_cycles_fused(
                     st.fabric, self.tables, space, st.eps.eg, st.eps.eg_ready,
-                    st.eps.eg_head, st.eps.eg_cnt, cycle, k,
-                    backend=self.params.backend))
+                    st.eps.eg_head, st.eps.eg_cnt, cycle, k))
             eps = dataclasses.replace(st.eps, eg=eg, eg_ready=eg_ready,
                                       eg_head=eg_head, eg_cnt=eg_cnt)
             # [C, k, ...] -> [k, C, ...] for the per-cycle endpoint replay

@@ -1,4 +1,4 @@
-"""Pallas backend for the FlooNoC router cycle: K-router tiles, fused cycles.
+"""Pallas backend for the FlooNoC router cycle: K-router tiles.
 
 One simulated cycle of the channel-batched fabric is two ``pallas_call``s,
 each with ``grid=(n_channels, n_routers / K)`` — one program per (channel,
@@ -27,14 +27,6 @@ second-to-last axis of the ``[C, R, P]`` counter blocks, which the TPU
 tiles by 8 sublanes, so ``effective_tile`` only picks multiples of 8 that
 divide R, or the whole fabric.
 
-``router_cycles_fused_pallas`` runs N simulated cycles in one kernel per
-channel: a ``fori_loop`` whose carry (the whole channel's fabric state plus
-the endpoint egress queues) stays resident in kernel memory, with
-``input_output_aliases`` donating the state buffers in place. Its body
-(``ref.fused_cycle_body``) gathers across routers inside the kernel, so it
-runs in interpret mode only; on a TPU ``ops.router_cycles_fused`` refuses
-it (see ``FUSED_TPU_REFUSAL``).
-
 All decision math is imported from ``repro.kernels.noc_router.ref`` — the
 functions are rank-generic over the leading router axis, so the Pallas
 programs (R-blocks of K) execute the very same code as the vmapped jnp
@@ -54,14 +46,6 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.noc_router import ref
 from repro.kernels.noc_router.ref import NF, NRED
-
-# what the TPU compiler (Mosaic) refuses in the fused multi-cycle kernel,
-# which therefore runs in interpret mode only; ops.py raises this on a TPU
-FUSED_TPU_REFUSAL = (
-    "the fused multi-cycle Pallas kernel does not compile for TPU: Mosaic "
-    "refuses its per-channel (1, E) blocks (the last two block dims must be "
-    "multiples of (8, 128) or the whole array) and has no lowering for the "
-    "gathers of ref.fused_cycle_body; use fused_cycles=1 or backend='jnp'")
 
 
 def effective_tile(router_tile: int, n_routers: int) -> int:
@@ -270,158 +254,3 @@ def router_cycle_pallas(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
                 red_new[0], red_new[1])
     return in2, in_cnt2, out2, out_cnt2, rr2, wh2, ep_flit, ep_valid
 
-
-def _fused_impl(in_buf_ref, in_cnt_ref, out_buf_ref, out_cnt_ref, rr_ref,
-                wh_ref, eg_ref, eg_ready_ref, eg_head_ref, eg_cnt_ref,
-                route_ref, link_src_ref, link_dst_ref, port_ep_ref,
-                ep_attach_ref, ep_space_ref, cycle0_ref,
-                nin_buf_ref, nin_cnt_ref, nout_buf_ref, nout_cnt_ref,
-                nrr_ref, nwh_ref, neg_ref, neg_ready_ref, neg_head_ref,
-                neg_cnt_ref, deliver_f_ref, deliver_v_ref, waiting_ref,
-                vc_out, n_cycles: int, n_vcs: int):
-    """N fused fabric cycles for one channel, state resident in the loop.
-
-    The carry (fabric state + this channel's circular egress queue) lives
-    in kernel values across the ``fori_loop`` — VMEM on TPU — touching the
-    output refs only once at the end; per-cycle deliveries and waiting
-    masks are streamed out at their cycle index. Shared body of the
-    default and VC kernels (``vc_out=None, n_vcs=1`` traces exactly the
-    historical kernel).
-    """
-    carry = (in_buf_ref[0], in_cnt_ref[0], out_buf_ref[0], out_cnt_ref[0],
-             rr_ref[0], wh_ref[0], eg_ref[0], eg_ready_ref[0],
-             eg_head_ref[0], eg_cnt_ref[0])
-    route = route_ref[...]
-    link_src = link_src_ref[...]
-    link_dst = link_dst_ref[...]
-    port_ep = port_ep_ref[...]
-    ep_attach = ep_attach_ref[...]
-    ep_space = ep_space_ref[0]
-    cycle0 = cycle0_ref[0]
-
-    def body(i, carry):
-        carry, (ep_flit, ep_valid, waiting) = ref.fused_cycle_body(
-            i, carry, route, link_src, link_dst, port_ep, ep_attach,
-            ep_space, cycle0, n_cycles, vc_out=vc_out, n_vcs=n_vcs)
-        at = (slice(0, 1), pl.ds(i, 1))
-        deliver_f_ref[at] = ep_flit[None, None]
-        deliver_v_ref[at] = ep_valid[None, None]
-        waiting_ref[at] = waiting[None, None]
-        return carry
-
-    carry = jax.lax.fori_loop(0, n_cycles, body, carry)
-    for out_ref, val in zip(
-            (nin_buf_ref, nin_cnt_ref, nout_buf_ref, nout_cnt_ref, nrr_ref,
-             nwh_ref, neg_ref, neg_ready_ref, neg_head_ref, neg_cnt_ref),
-            carry):
-        out_ref[...] = val[None]
-
-
-def _fused_kernel(*refs, n_cycles: int):
-    """Default (VC-less) fused kernel: the historical operand list."""
-    _fused_impl(*refs, vc_out=None, n_cycles=n_cycles, n_vcs=1)
-
-
-def _fused_kernel_vc(in_buf_ref, in_cnt_ref, out_buf_ref, out_cnt_ref,
-                     rr_ref, wh_ref, eg_ref, eg_ready_ref, eg_head_ref,
-                     eg_cnt_ref, route_ref, vc_out_ref, *rest,
-                     n_cycles: int, n_vcs: int):
-    """VC fused kernel: ``vc_out`` rides as one extra table operand after
-    ``route``; everything else is the shared body."""
-    _fused_impl(in_buf_ref, in_cnt_ref, out_buf_ref, out_cnt_ref, rr_ref,
-                wh_ref, eg_ref, eg_ready_ref, eg_head_ref, eg_cnt_ref,
-                route_ref, *rest, vc_out=vc_out_ref[...], n_cycles=n_cycles,
-                n_vcs=n_vcs)
-
-
-def router_cycles_fused_pallas(in_buf, in_cnt, out_buf, out_cnt, rr_ptr,
-                               wh_lock, eg, eg_ready, eg_head, eg_cnt,
-                               route, link_src, link_dst, port_ep, ep_attach,
-                               ep_space, cycle0, n_cycles: int, *,
-                               interpret: bool = False, vc_out=None,
-                               n_vcs: int = 1):
-    """``n_cycles`` fused fabric cycles, one program per channel.
-
-    Inputs are channel-batched state (+ the circular egress queues ``eg``
-    [C, E, Q, NF] / ``eg_ready`` [C, E, Q] / ``eg_head``/``eg_cnt``
-    [C, E]); ``cycle0`` is the window's first cycle number (traced scalar).
-    The state inputs are aliased onto the outputs (donated in place).
-    With ``n_vcs > 1`` the P axis is slot-level and ``vc_out`` [R, P, Pp]
-    rides along as one extra shared table. Returns ``(state'..., eg'...,
-    ep_flit [C, N, E, NF], ep_valid [C, N, E], req_waiting [C, N, E])`` —
-    identical, bit for bit, to ``ref.router_cycles_scan`` vmapped over
-    channels.
-    """
-    C, R, P = in_cnt.shape
-    Din = in_buf.shape[-2]
-    Dout = out_buf.shape[-2]
-    E, Q = eg_ready.shape[-2:]
-    Pp = P // n_vcs  # physical ports (== P when n_vcs == 1)
-    i32 = jnp.int32
-    N = n_cycles
-
-    chan_spec = lambda *tail: pl.BlockSpec(
-        (1, *tail), lambda c: (c,) + (0,) * len(tail))
-    full_spec = lambda *shape: pl.BlockSpec(shape, lambda c: (0,) * len(shape))
-
-    state_shapes = [
-        jax.ShapeDtypeStruct((C, R, P, Din, NF), i32),  # in_buf
-        jax.ShapeDtypeStruct((C, R, P), i32),  # in_cnt
-        jax.ShapeDtypeStruct((C, R, P, Dout, NF), i32),  # out_buf
-        jax.ShapeDtypeStruct((C, R, P), i32),  # out_cnt
-        jax.ShapeDtypeStruct((C, R, P), i32),  # rr_ptr
-        jax.ShapeDtypeStruct((C, R, P), i32),  # wh_lock
-        jax.ShapeDtypeStruct((C, E, Q, NF), i32),  # eg
-        jax.ShapeDtypeStruct((C, E, Q), i32),  # eg_ready
-        jax.ShapeDtypeStruct((C, E), i32),  # eg_head
-        jax.ShapeDtypeStruct((C, E), i32),  # eg_cnt
-    ]
-    state_specs = [
-        chan_spec(R, P, Din, NF),
-        chan_spec(R, P),
-        chan_spec(R, P, Dout, NF),
-        chan_spec(R, P),
-        chan_spec(R, P),
-        chan_spec(R, P),
-        chan_spec(E, Q, NF),
-        chan_spec(E, Q),
-        chan_spec(E),
-        chan_spec(E),
-    ]
-
-    if n_vcs == 1:
-        kern = functools.partial(_fused_kernel, n_cycles=N)
-        vc_tables, vc_specs = [], []
-    else:
-        kern = functools.partial(_fused_kernel_vc, n_cycles=N, n_vcs=n_vcs)
-        vc_tables, vc_specs = [vc_out], [full_spec(R, P, Pp)]
-    outs = pl.pallas_call(
-        kern,
-        grid=(C,),
-        in_specs=state_specs + [
-            full_spec(R, E),  # route
-            *vc_specs,  # vc_out (V > 1 only)
-            full_spec(R, P, 2),  # link_src (slot-level)
-            full_spec(R, P, 2),  # link_dst
-            full_spec(R, P),  # port_ep (slot-level)
-            full_spec(E, 2),  # ep_attach
-            chan_spec(E),  # ep_space
-            full_spec(1),  # cycle0
-        ],
-        out_specs=state_specs + [
-            chan_spec(N, E, NF),  # deliveries
-            chan_spec(N, E),  # delivery valid
-            chan_spec(N, E),  # req_waiting
-        ],
-        out_shape=state_shapes + [
-            jax.ShapeDtypeStruct((C, N, E, NF), i32),
-            jax.ShapeDtypeStruct((C, N, E), jnp.bool_),
-            jax.ShapeDtypeStruct((C, N, E), jnp.bool_),
-        ],
-        input_output_aliases={i: i for i in range(len(state_specs))},
-        interpret=interpret,
-    )(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
-      eg, eg_ready, eg_head, eg_cnt,
-      route, *vc_tables, link_src, link_dst, port_ep, ep_attach, ep_space,
-      jnp.reshape(jnp.asarray(cycle0, i32), (1,)))
-    return outs

@@ -1,4 +1,4 @@
-"""Public entry points: router-fabric cycles, backend-dispatched.
+"""Public entry point: one router-fabric cycle, backend-dispatched.
 
 ``router_cycle(..., backend="jnp" | "pallas")`` runs one cycle of the
 channel-batched fabric on raw arrays. ``"jnp"`` vmaps the reference
@@ -11,17 +11,10 @@ decision functions from ``ref.py`` and are bit-identical — pinned by
 datapath on both backends (identical live contents either way; the flag
 must simply match across a bit-exact comparison).
 
-``router_cycles_fused(...)`` advances the fabric N cycles per call with
-endpoint egress injection threaded through (the multi-cycle super-step):
-``"jnp"`` scans ``ref.fused_cycle_body``, ``"pallas"`` runs the same body
-inside one kernel per channel with the state resident across the loop.
-
-On a TPU the per-cycle kernels compile for every datapath: default, VC
-and collective offload (``tests/test_tpu_compile.py`` compiles them for a
-described v5e). The fused multi-cycle kernel does not: with ``interpret``
-False it raises ``NotImplementedError`` naming what the TPU compiler
-refuses. Nothing falls back to interpret mode or to the jnp path on a
-TPU.
+On a TPU the kernels compile for every datapath: default, VC and
+collective offload (``tests/test_tpu_compile.py`` compiles them for a
+described v5e). Nothing falls back to interpret mode or to the jnp path
+on a TPU.
 
 This module is deliberately free of ``repro.core.noc`` imports: the engine
 layers on top of it, not the other way around.
@@ -32,29 +25,13 @@ import functools
 
 import jax
 
-from repro.kernels.noc_router.noc_router import (
-    FUSED_TPU_REFUSAL,
-    router_cycle_pallas,
-    router_cycles_fused_pallas,
-)
+from repro.kernels.noc_router.noc_router import router_cycle_pallas
 from repro.kernels.noc_router.ref import (
     router_cycle_offload_reference,
     router_cycle_reference,
-    router_cycles_scan,
 )
 
 BACKENDS = ("jnp", "pallas")
-
-# vmap the single-channel reference over the leading channel axis of the
-# state and the per-channel endpoint ingress space; tables are shared.
-_cycle_jnp = jax.vmap(
-    router_cycle_reference,
-    in_axes=(0, 0, 0, 0, 0, 0, None, None, None, None, None, 0),
-)
-_cycle_jnp_fused = jax.vmap(
-    functools.partial(router_cycle_reference, fused=True),
-    in_axes=(0, 0, 0, 0, 0, 0, None, None, None, None, None, 0),
-)
 
 
 def _interp(interpret):
@@ -84,9 +61,8 @@ def router_cycle(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
     ``red_got`` [C, R, G, P]) selects the offload datapath on both
     backends and extends the return tuple to ``(..., red_acc', red_got')``.
     """
-    offload = fork_out is not None
     if backend == "jnp":
-        if offload:
+        if fork_out is not None:
             fn = jax.vmap(
                 functools.partial(router_cycle_offload_reference,
                                   n_endpoints=n_endpoints, fused=fused_fifo,
@@ -96,14 +72,14 @@ def router_cycle(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
             return fn(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
                       red_acc, red_got, route, link_src, link_dst, port_ep,
                       ep_attach, fork_out, red_parent, red_need, ep_space)
-        if n_vcs > 1:
-            fn = jax.vmap(
-                functools.partial(router_cycle_reference, fused=fused_fifo,
-                                  vc_out=vc_out, n_vcs=n_vcs),
-                in_axes=(0, 0, 0, 0, 0, 0, None, None, None, None, None, 0),
-            )
-        else:
-            fn = _cycle_jnp_fused if fused_fifo else _cycle_jnp
+        # vmap the single-channel reference over the leading channel axis of
+        # the state and the per-channel endpoint ingress space; tables are
+        # shared.
+        fn = jax.vmap(
+            functools.partial(router_cycle_reference, fused=fused_fifo,
+                              vc_out=vc_out, n_vcs=n_vcs),
+            in_axes=(0,) * 6 + (None,) * 5 + (0,),
+        )
         return fn(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
                   route, link_src, link_dst, port_ep, ep_attach, ep_space)
     if backend == "pallas":
@@ -119,58 +95,3 @@ def router_cycle(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
                                    red_got=red_got, n_endpoints=n_endpoints)
     raise ValueError(f"unknown router backend {backend!r}; expected one of {BACKENDS}")
 
-
-# vmap the single-channel fused scan over the channel axis: state + egress
-# queues and ep_space are per-channel, tables and the cycle base are shared.
-# out_axes puts the per-cycle outputs at [C, N, ...] like the kernel.
-_cycles_scan_jnp = jax.vmap(
-    router_cycles_scan,
-    in_axes=(0,) * 10 + (None,) * 5 + (0, None, None),
-    out_axes=(0, 0),
-)
-
-
-def router_cycles_fused(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
-                        eg, eg_ready, eg_head, eg_cnt,
-                        route, link_src, link_dst, port_ep, ep_attach,
-                        ep_space, cycle0, n_cycles: int, *,
-                        backend: str = "jnp", interpret=None,
-                        vc_out=None, n_vcs: int = 1):
-    """``n_cycles`` fused fabric cycles with egress injection threaded in.
-
-    Same array contract as :func:`router_cycle` plus this channel-batched
-    circular egress queue (``eg`` [C, E, Q, NF], ``eg_ready`` [C, E, Q],
-    ``eg_head``/``eg_cnt`` [C, E]) and the window's first cycle number
-    ``cycle0``. ``ep_space`` is sampled once and held for the window (the
-    k=1 window is bit-identical to per-cycle stepping; see
-    ``sim.Sim.step_super`` for the k>1 contract). Returns the 10 updated
-    state arrays plus ``(ep_flit [C, N, E, NF], ep_valid [C, N, E],
-    req_waiting [C, N, E])``. Backends are bit-identical (same
-    ``ref.fused_cycle_body``).
-    """
-    if backend == "jnp":
-        if n_vcs > 1:
-            scan_fn = jax.vmap(
-                functools.partial(router_cycles_scan, vc_out=vc_out,
-                                  n_vcs=n_vcs),
-                in_axes=(0,) * 10 + (None,) * 5 + (0, None, None),
-                out_axes=(0, 0),
-            )
-        else:
-            scan_fn = _cycles_scan_jnp
-        carry, (ep_flit, ep_valid, waiting) = scan_fn(
-            in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
-            eg, eg_ready, eg_head, eg_cnt,
-            route, link_src, link_dst, port_ep, ep_attach,
-            ep_space, cycle0, n_cycles)
-        return (*carry, ep_flit, ep_valid, waiting)
-    if backend == "pallas":
-        if not _interp(interpret):
-            raise NotImplementedError(FUSED_TPU_REFUSAL)
-        return router_cycles_fused_pallas(
-            in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
-            eg, eg_ready, eg_head, eg_cnt,
-            route, link_src, link_dst, port_ep, ep_attach,
-            ep_space, cycle0, n_cycles, interpret=True,
-            vc_out=vc_out, n_vcs=n_vcs)
-    raise ValueError(f"unknown router backend {backend!r}; expected one of {BACKENDS}")

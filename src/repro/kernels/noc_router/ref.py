@@ -1,9 +1,9 @@
 """Reference implementation of one FlooNoC router cycle (single channel).
 
-This is the bit-exact specification of the per-cycle router datapath that
-used to live inline in ``repro.core.noc.engine._cycle_one``: cycle-start
-snapshot semantics, round-robin output arbitration, wormhole-lock updates,
-and FIFO push/pop over packed ``[R, P, D, NF]`` flit state.
+This is the bit-exact specification of the per-cycle router datapath:
+cycle-start snapshot semantics, round-robin output arbitration,
+wormhole-lock updates, and FIFO push/pop over packed ``[R, P, D, NF]``
+flit state.
 
 The decision functions are written **rank-generically over the leading
 router axis**: every operation addresses the port/fifo/field axes by their
@@ -556,8 +556,7 @@ def router_cycle_reference(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
     All state is single-channel ([R, P, ...]); ``ep_space`` [E] is the
     endpoint ingress-space mask for this channel. Returns
     ``(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock, ep_flit [E, NF],
-    ep_valid [E])``. This is the extracted body of the original
-    ``engine._cycle_one`` and the bit-exact specification the Pallas
+    ep_valid [E])``. This is the bit-exact specification the Pallas
     backend is tested against. ``fused`` selects the fused FIFO datapath
     (the fast/Pallas default; identical on live slots). ``n_vcs > 1``
     selects the virtual-channel datapath (folded slot axis P = phys *
@@ -648,9 +647,8 @@ def fused_cycle_body(i, carry, route, link_src, link_dst, port_ep, ep_attach,
     stepping). Returns ``(carry', (ep_flit [E, NF], ep_valid [E],
     req_waiting [E]))``.
 
-    This body is the single source of truth for both fused backends: the
-    jnp path scans it, the Pallas kernel runs it inside ``fori_loop`` with
-    the carry resident in kernel memory.
+    ``router_cycles_scan`` scans it; ``engine.fabric_cycles_fused`` vmaps
+    that scan over channels.
     """
     (in_buf, in_cnt, out_buf, out_cnt, rr, wh,
      eg, eg_ready, eg_head, eg_cnt) = carry
@@ -684,7 +682,6 @@ def router_cycles_scan(in_buf, in_cnt, out_buf, out_cnt, rr_ptr, wh_lock,
                        n_vcs: int = 1):
     """``n_cycles`` of ``fused_cycle_body`` as a lax.scan (single channel).
 
-    The jnp reference for the fused Pallas kernel: same body, same order.
     Returns ``(carry', (ep_flit [N, E, NF], ep_valid [N, E],
     req_waiting [N, E]))``.
     """

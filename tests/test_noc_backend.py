@@ -34,44 +34,37 @@ def _assert_states_equal(a, b, tag=""):
                                       err_msg=tag)
 
 
-# zoo configs for the per-cycle tiled kernel and the fused multi-cycle
-# kernel: (name, build kwargs, n_channels, streams, router_tile,
-# fused_cycles). With fused_cycles=1 the grid is (C, R / K) with
+# zoo configs for the tiled kernel: (name, build kwargs, n_channels,
+# streams, router_tile). The grid is (C, R / K) with
 # K = effective_tile(router_tile, R): router_tile 8 splits the 16-, 24- and
 # 32-router fabrics into 2, 3 and 4 blocks whose links cross block edges,
-# router_tile 0 is the whole fabric per program (K = R). fused_cycles > 1
-# runs k cycles per pallas_call, one program per channel (router_tile is
-# unused there), with state resident across the window.
+# router_tile 0 is the whole fabric per program (K = R).
 ZOO = [
-    ("mesh", dict(nx=4, ny=4), 3, 1, 8, 1),
-    ("mesh", dict(nx=8, ny=4), 4, 2, 8, 1),
-    ("torus", dict(nx=4, ny=2), 3, 1, 0, 1),
-    ("torus", dict(nx=4, ny=2), 4, 2, 1, 4),
-    ("multi_die", dict(n_dies=2, nx=2, ny=2, d2d=2), 3, 1, 4, 4),
-    ("multi_die", dict(n_dies=2, nx=2, ny=2, d2d=2), 4, 2, 0, 4),
-    ("torus", dict(nx=4, ny=4), 4, 2, 8, 1),
-    ("multi_die", dict(n_dies=2, nx=2, ny=4, d2d=2), 3, 1, 8, 1),
+    ("mesh", dict(nx=4, ny=4), 3, 1, 8),
+    ("mesh", dict(nx=8, ny=4), 4, 2, 8),
+    ("torus", dict(nx=4, ny=2), 3, 1, 0),
+    ("torus", dict(nx=4, ny=2), 4, 2, 0),
+    ("multi_die", dict(n_dies=2, nx=2, ny=2, d2d=2), 3, 1, 0),
+    ("multi_die", dict(n_dies=2, nx=2, ny=2, d2d=2), 4, 2, 0),
+    ("torus", dict(nx=4, ny=4), 4, 2, 8),
+    ("multi_die", dict(n_dies=2, nx=2, ny=4, d2d=2), 3, 1, 8),
 ]
 
 
-@pytest.mark.parametrize("name,kw,channels,streams,tile,fused", ZOO)
-def test_pallas_matches_jnp_state_bitexact(name, kw, channels, streams,
-                                           tile, fused):
-    """Full SimState after 300 cycles is identical leaf-for-leaf, for the
-    per-cycle tiled kernel (fused_cycles=1, K routers per program) and the
-    fused multi-cycle kernel (fused_cycles=k) alike — each against the jnp
-    reference with the same stepping knobs."""
+@pytest.mark.parametrize("name,kw,channels,streams,tile", ZOO)
+def test_pallas_matches_jnp_state_bitexact(name, kw, channels, streams, tile):
+    """Full SimState after 300 cycles is identical leaf-for-leaf between
+    the tiled kernel (K routers per program) and the jnp reference."""
     topo = build_topology(name, **kw)
-    if fused == 1 and tile:  # the grid really has several router blocks
+    if tile:  # the grid really has several router blocks
         assert effective_tile(tile, topo.n_routers) < topo.n_routers
     wl = T.dma_workload(topo, "uniform", transfer_kb=1, n_txns=2,
                         streams=streams)
-    stj = S.run(S.build_sim(
-        topo, NocParams(n_channels=channels, fused_cycles=fused), wl), 300)
+    stj = S.run(S.build_sim(topo, NocParams(n_channels=channels), wl), 300)
     stp = S.run(S.build_sim(
         topo, NocParams(n_channels=channels, backend="pallas",
-                        router_tile=tile, fused_cycles=fused), wl), 300)
-    _assert_states_equal(stj, stp, f"{name} C={channels} K={tile} k={fused}")
+                        router_tile=tile), wl), 300)
+    _assert_states_equal(stj, stp, f"{name} C={channels} K={tile}")
 
 
 def test_pallas_reproduces_golden_stat_pins():
@@ -127,18 +120,11 @@ def test_pallas_run_sweep_matches_jnp():
 def test_backend_validation():
     with pytest.raises(ValueError):
         NocParams(backend="tpu")
+    # the fused multi-cycle window runs on the jnp backend only
+    with pytest.raises(ValueError, match="backend.*fused_cycles"):
+        NocParams(backend="pallas", fused_cycles=4)
     from repro.kernels.noc_router import ops
 
     with pytest.raises(ValueError):
         ops.router_cycle(*([None] * 12), backend="nope")
 
-
-def test_fused_pallas_refused_on_tpu():
-    """The fused multi-cycle kernel does not compile for the TPU: asked to
-    run compiled, it raises with the compiler's reason instead of falling
-    back to interpret mode or to the jnp path."""
-    from repro.kernels.noc_router import ops
-
-    with pytest.raises(NotImplementedError, match="does not compile for TPU"):
-        ops.router_cycles_fused(*([None] * 17), 4, backend="pallas",
-                                interpret=False)

@@ -39,6 +39,7 @@ def _sim(params=None, write=True, name="torus", **kw):
     ("mesh", dict(nx=4, ny=2)),
     ("torus", dict(nx=4, ny=2)),
     ("multi_die", dict(n_dies=2, nx=2, ny=2, d2d=2)),
+    ("mesh", dict(nx=8, ny=4)),  # the paper's 8x4 mesh
 ])
 def test_fast_matches_naive_canonical(name, kw):
     """step_impl='fast' and 'naive' agree on the canonical SimState (live
